@@ -118,7 +118,12 @@ _DIST_SNIPPET = textwrap.dedent("""
 
 
 def _distributed_rows():
+    """Rows of the 8-device host mesh, from a child process pinned to
+    the CPU: these are host rows by design, and a child that went for an
+    accelerator would contend with this process, which holds it.  A
+    failed or timed-out child fails the whole run."""
     env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     env.setdefault("PYTHONPATH", "")
     src = os.path.join(os.path.dirname(os.path.abspath(__file__)), "..", "src")
@@ -127,17 +132,16 @@ def _distributed_rows():
     try:
         proc = subprocess.run([sys.executable, "-c", code], env=env,
                               capture_output=True, text=True, timeout=1200)
-    except (subprocess.TimeoutExpired, OSError) as e:
-        # degrade to local-only rows rather than losing the whole run
-        print(f"# distributed rows FAILED: {type(e).__name__}: {e}")
-        return []
+    except subprocess.TimeoutExpired as e:
+        raise RuntimeError(f"distributed rows timed out after {e.timeout}s")
     if proc.returncode != 0:
-        print(f"# distributed rows FAILED:\n{proc.stderr[-2000:]}")
-        return []
+        raise RuntimeError(
+            f"distributed rows failed (rc {proc.returncode}):\n"
+            f"{proc.stderr[-2000:]}")
     for line in proc.stdout.splitlines():
         if line.startswith("JSON:"):
             return json.loads(line[5:])
-    return []
+    raise RuntimeError("distributed rows: the child printed no JSON line")
 
 
 def _geomean(ratios, label: str = "") -> float:

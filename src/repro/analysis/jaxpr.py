@@ -12,9 +12,9 @@ The walk recurses through every higher-order primitive generically
 a Jaxpr/ClosedJaxpr (or a tuple/list of them) is entered.  Two
 primitives get special handling:
 
-  * ``shard_map`` — establishes the manual-axis context.  Its ``auto``
-    parameter names the mesh axes that stay under GSPMD inside the
-    region; everything else is manual.  Collectives recorded inside
+  * ``shard_map`` — establishes the manual-axis context.  Its
+    ``manual_axes`` parameter names the manual mesh axes; the rest stay
+    under GSPMD inside the region.  Collectives recorded inside
     carry that context, which is what the ``no-collective-over-auto-
     axis`` rule (the PR-5 XLA SPMD crash class) reads.
   * ``scan``/``while`` — multiply the trip count into every op of the
@@ -77,17 +77,21 @@ class _Walk:
     def __init__(self):
         self.ops = []
         self.notes = {}
-        self.n_eqns = 0     # global collective-eqn counter -> op.group
+        self.n_eqns = 0     # global collective-call counter -> op.group
 
     def walk(self, jaxpr, mult=1.0, manual=(), auto=(), in_sm=False):
+        prev = None         # (prim, axes, source) of the preceding eqn
         for eqn in jaxpr.eqns:
             name = eqn.primitive.name
+            key, prev = prev, None
 
             if name == "shard_map":
                 mesh = eqn.params.get("mesh")
-                auto_axes = tuple(sorted(eqn.params.get("auto", ()) or ()))
                 names = tuple(getattr(mesh, "axis_names", ()))
-                man = tuple(a for a in names if a not in auto_axes)
+                in_manual = eqn.params.get("manual_axes", frozenset(names))
+                man = tuple(a for a in names if a in in_manual)
+                auto_axes = tuple(sorted(a for a in names
+                                         if a not in in_manual))
                 for sub in _sub_jaxprs(eqn.params.get("jaxpr")):
                     self.walk(sub, mult, man, auto_axes, True)
                 continue
@@ -96,12 +100,15 @@ class _Walk:
             if kind is not None:
                 axes = _axis_names(eqn.params)
                 # one record per payload operand: a psum of a stats dict
-                # binds several arrays in one eqn, and rules reason
-                # per-array (shape/dtype).  ``group`` ties the operands
-                # of ONE eqn back together — the masked-psum-validity
-                # rule reasons about a whole stats psum at once.
-                gid = self.n_eqns
-                self.n_eqns += 1
+                # binds one eqn per leaf, back to back from one source
+                # line, and rules reason per-array (shape/dtype).
+                # ``group`` ties the operands of ONE such call back
+                # together — the masked-psum-validity rule reasons about
+                # a whole stats psum at once.
+                prev = (name, axes, _source(eqn))
+                if prev != key:
+                    self.n_eqns += 1
+                gid = self.n_eqns - 1
                 outs = eqn.outvars if kind != "reduce_scatter" \
                     else eqn.invars
                 for v in (outs or eqn.outvars):

@@ -1,65 +1,31 @@
-"""JAX API compatibility shims.
+"""The JAX mesh/shard_map surface the repo uses, in one place.
 
-The repo targets the modern shard_map surface (``jax.shard_map`` with
-``axis_names``/``check_vma``, ``jax.sharding.AxisType`` meshes,
-``jax.lax.axis_size``) but must also run on the 0.4.x line, where the
-same features live under different names:
-
-  new (>= 0.5)                         old (0.4.x)
-  ------------------------------------ -----------------------------------
-  jax.shard_map(..., axis_names=M,     jax.experimental.shard_map.shard_map(
-               check_vma=...)              ..., auto=mesh_axes - M,
-                                           check_rep=...)
-  jax.make_mesh(..., axis_types=Auto)  jax.make_mesh(...)  (no axis types)
-  jax.lax.axis_size(axes)              jax.lax.psum(1, axes)
-
-Everything that touches these APIs imports from here, never from jax
-directly, so the version split lives in exactly one file.
+Everything that builds meshes or enters shard_map imports from here,
+never from jax directly, so a future API move touches one file.
 """
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 from jax.sharding import PartitionSpec as P  # noqa: F401  (re-export)
 
 __all__ = ["P", "axis_size", "make_mesh", "shard_map"]
 
 
 def make_mesh(shape, axes):
-    """Mesh with Auto axis types where the concept exists."""
-    try:
-        from jax.sharding import AxisType
-        return jax.make_mesh(shape, axes,
-                             axis_types=(AxisType.Auto,) * len(axes))
-    except ImportError:
-        return jax.make_mesh(shape, axes)
+    """Mesh with Auto axis types."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
-if hasattr(jax, "shard_map"):
-
-    def shard_map(f, *, mesh, in_specs, out_specs, axis_names=None,
-                  check_vma=False):
-        kw = dict(mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_vma=check_vma)
-        if axis_names is not None:
-            kw["axis_names"] = set(axis_names)
-        return jax.shard_map(f, **kw)
-
-else:
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    def shard_map(f, *, mesh, in_specs, out_specs, axis_names=None,
-                  check_vma=False):
-        auto = frozenset()
-        if axis_names is not None:
-            auto = frozenset(mesh.axis_names) - frozenset(axis_names)
-        return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, check_rep=check_vma,
-                          auto=auto)
+def shard_map(f, *, mesh, in_specs, out_specs, axis_names=None,
+              check_vma=False):
+    kw = dict(mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+              check_vma=check_vma)
+    if axis_names is not None:
+        kw["axis_names"] = set(axis_names)
+    return jax.shard_map(f, **kw)
 
 
 def axis_size(axes) -> int:
     """Product of the named mesh axis sizes (inside shard_map)."""
-    if hasattr(jax.lax, "axis_size"):
-        return int(jax.lax.axis_size(axes))
-    # psum of the python constant 1 resolves statically to the axis size
-    return int(jax.lax.psum(1, axes))
+    return int(jax.lax.axis_size(axes))

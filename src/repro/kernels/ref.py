@@ -181,9 +181,11 @@ def fused_stats_ref(G, needs, axis: int = 0) -> dict:
             lambda c, g: (c + (g >= mean_c).astype(jnp.int32), None),
             jnp.zeros(x.shape[1:], jnp.int32), x)
         majority_is_above = n_above * 2 >= m
+        # integer counts: an f32 sum of 0/1 is inexact past 2^24 columns
         out["scores"] = jax.lax.map(
             lambda g: jnp.sum(jnp.where(majority_is_above, g >= mean_c,
-                                        g < mean_c).astype(jnp.float32)), x)
+                                        g < mean_c), dtype=jnp.int32)
+            .astype(jnp.float32), x)
     if "l1" in needs or "d2med" in needs:
         med = median_from_sorted(sorted_worker_rows(x))
         def dists(g):
@@ -216,7 +218,7 @@ def majority_score_ref(G):
     n_above = jnp.sum(above, axis=0, keepdims=True)          # [1,d]
     majority_is_above = n_above * 2 >= m                     # counter >= m/2
     M = jnp.where(majority_is_above, above, ~above)
-    return jnp.sum(M.astype(jnp.float32), axis=1)            # [m]
+    return jnp.sum(M, axis=1, dtype=jnp.int32).astype(jnp.float32)  # [m]
 
 
 def l1_to_median_ref(G, med=None):
@@ -435,8 +437,8 @@ def masked_fused_stats_ref(G, needs, valid, axis: int = 0, rows=None,
         maj = refs["majority_is_above"]
         out["scores"] = jax.lax.map(
             lambda gr: gr[1] * jnp.sum(
-                jnp.where(maj, gr[0] >= mean_c, gr[0] < mean_c)
-                .astype(jnp.float32)), (x, r))
+                jnp.where(maj, gr[0] >= mean_c, gr[0] < mean_c),
+                dtype=jnp.int32).astype(jnp.float32), (x, r))
     if "l1" in needs or "d2med" in needs:
         med = refs["med"]
 

@@ -23,7 +23,8 @@ import numpy as np
 def run_serve_loop(args, cfg):
     """Continuous batching over a synthetic request stream; params come
     from the newest checkpoint under --ckpt-dir (hot-swapped live) or a
-    fresh init when no directory is given."""
+    fresh init when no directory is given.  Returns the drained
+    ServeLoop (answers in ``.done``, compile counters, metrics)."""
     import jax
 
     from ..checkpoint import ckpt
@@ -65,7 +66,7 @@ def run_serve_loop(args, cfg):
         with open(args.metrics_out, "w") as f:
             f.write(metrics)
         print(f"metrics -> {args.metrics_out}")
-    return done
+    return loop
 
 
 def main(argv=None):
@@ -91,6 +92,9 @@ def main(argv=None):
     ap.add_argument("--metrics-out", default=None,
                     help="[serve-loop] write the /metrics dump here")
     args = ap.parse_args(argv)
+
+    from . import compile_cache
+    compile_cache.enable()
 
     import jax
     import jax.numpy as jnp

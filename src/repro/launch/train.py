@@ -80,6 +80,9 @@ def main(argv=None):
     ap.add_argument("--log-every", type=int, default=1)
     args = ap.parse_args(argv)
 
+    from . import compile_cache
+    compile_cache.enable()
+
     import jax
     import jax.numpy as jnp
 

@@ -203,7 +203,7 @@ def test_auto_layout_matches_forced_layouts():
     code = textwrap.dedent("""
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import Mesh, PartitionSpec as P
-        from jax.experimental.shard_map import shard_map
+        from repro.compat import shard_map
         from repro.configs import ByzantineConfig
         from repro.core import engine
 

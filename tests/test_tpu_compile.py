@@ -1,0 +1,71 @@
+"""The aggregation kernels compile for a TPU v5e chip.
+
+Nothing runs: each kernel is lowered with ``interpret=False`` for a
+described (not attached) v5e topology and handed to the TPU compiler,
+which refuses what the chip would refuse — block shapes off the (8, 128)
+tiling, unsupported Mosaic ops, too much VMEM.  Widths: one qwen3-0.6b
+MLP matrix (1024 x 3072) and a width that is no multiple of 128 (a2a
+chunks have arbitrary widths).
+
+The topology is described inside a module fixture, never at import:
+only one process may load the TPU library, and every test worker
+imports this file.
+"""
+import importlib
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.kernels import ref
+
+K = importlib.import_module("repro.kernels.brsgd_stats")
+
+KERNELS = {
+    "fused_stats": lambda G: K.fused_stats_pallas(G, ref.STAT_NAMES,
+                                                  interpret=False),
+    "brsgd_stats": lambda G: K.brsgd_stats_pallas(G, interpret=False),
+    "cwise_median": lambda G: K.cwise_median_pallas(G, interpret=False),
+    "select_mean": lambda G: K.select_mean_pallas(
+        G, jnp.ones(G.shape[0]), jnp.ones(G.shape[0]), 0.5, 0.0,
+        interpret=False),
+    "masked_mean": lambda G: K.masked_mean_pallas(
+        G, jnp.ones(G.shape[0]), interpret=False),
+    "trimmed_mean": lambda G: K.trimmed_mean_pallas(G, 0.25,
+                                                    interpret=False),
+}
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        t = topologies.get_topology_desc(platform="tpu",
+                                         topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a compile for a described chip cannot be read back from the
+    # persistent cache without one: keep these compiles out of it
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield t
+    jax.config.update("jax_enable_compilation_cache", was)
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.mark.parametrize("d", [1024 * 3072, 1000])
+@pytest.mark.parametrize("m", [4, 8])
+@pytest.mark.parametrize("kernel", sorted(KERNELS))
+def test_kernel_compiles_for_v5e(one_chip, kernel, m, d):
+    G = jax.ShapeDtypeStruct((m, d), jnp.float32, sharding=one_chip)
+    compiled = jax.jit(KERNELS[kernel]).lower(G).compile()
+    assert "tpu_custom_call" in compiled.as_text()
