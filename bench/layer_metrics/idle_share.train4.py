@@ -1,0 +1,7 @@
+"""``idle_share.train`` of the four-chip training cells, which report
+``train4_tokens_per_s``."""
+from bench import common
+
+
+def read(ctx):
+    return common.layer_metric("idle_share.train").read(ctx)
