@@ -336,13 +336,14 @@ def make_fsdp_agg_barrier(specs, bcfg: ByzantineConfig, axes, name: str,
             key = jax.lax.bitcast_convert_type(keyf, jnp.uint32)
             key_l = jax.random.fold_in(bucket_key(key, name),
                                        idx.astype(jnp.int32))
-            g_full = threat.inject(g_full, key_l, bcfg, axes,
-                                   membership_key=key, active=activef)
-            agg, st = _bucket_aggregate(g_full, specs, bcfg, axes,
-                                        valid=activef)
-            m = axis_size(axes)
-            n_sel = jnp.sum(st.selected.astype(jnp.int32))
-            hist = jax.nn.one_hot(n_sel, m + 1, dtype=jnp.float32)
+            with jax.named_scope("aggregate"):
+                g_full = threat.inject(g_full, key_l, bcfg, axes,
+                                       membership_key=key, active=activef)
+                agg, st = _bucket_aggregate(g_full, specs, bcfg, axes,
+                                            valid=activef)
+                m = axis_size(axes)
+                n_sel = jnp.sum(st.selected.astype(jnp.int32))
+                hist = jax.nn.one_hot(n_sel, m + 1, dtype=jnp.float32)
             return (agg, hist, jnp.zeros((), jnp.float32),
                     jnp.zeros_like(keyf), jnp.zeros_like(activef))
 
@@ -363,12 +364,13 @@ def make_fsdp_agg_barrier(specs, bcfg: ByzantineConfig, axes, name: str,
         key = jax.lax.bitcast_convert_type(keyf, jnp.uint32)
         key_l = jax.random.fold_in(bucket_key(key, name),
                                    idx.astype(jnp.int32))
-        g_full = threat.inject(g_full, key_l, bcfg, axes,
-                               membership_key=key)
-        agg, st = _bucket_aggregate(g_full, specs, bcfg, axes)
-        m = axis_size(axes)
-        n_sel = jnp.sum(st.selected.astype(jnp.int32))
-        hist = jax.nn.one_hot(n_sel, m + 1, dtype=jnp.float32)
+        with jax.named_scope("aggregate"):
+            g_full = threat.inject(g_full, key_l, bcfg, axes,
+                                   membership_key=key)
+            agg, st = _bucket_aggregate(g_full, specs, bcfg, axes)
+            m = axis_size(axes)
+            n_sel = jnp.sum(st.selected.astype(jnp.int32))
+            hist = jax.nn.one_hot(n_sel, m + 1, dtype=jnp.float32)
         return agg, hist, jnp.zeros((), jnp.float32), jnp.zeros_like(keyf)
 
     barrier.defvjp(fwd, bwd)

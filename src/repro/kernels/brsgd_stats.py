@@ -171,6 +171,7 @@ def fused_stats_pallas(G, needs, d_blk: int = 2048,
             for n, s in zip(needs, shapes)],
         compiler_params=_ACCUMULATE,
         interpret=interpret,
+        name="fused_stats",
     )(G)
     out = {}
     for n, p in zip(needs, parts):
@@ -215,6 +216,7 @@ def brsgd_stats_pallas(G, d_blk: int = 2048, interpret: bool = True):
            jax.ShapeDtypeStruct((m, 1), jnp.float32)],
         compiler_params=_ACCUMULATE,
         interpret=interpret,
+        name="brsgd_stats",
     )(G)
     # zero-pad columns scored 1 for every worker
     scores = (scores[:, 0] - pad).astype(jnp.float32)
@@ -269,6 +271,7 @@ def select_mean_pallas(G, scores, l1, beta: float, threshold,
                    jax.ShapeDtypeStruct((m, 1), jnp.float32)],
         compiler_params=_ACCUMULATE,
         interpret=interpret,
+        name="select_mean",
     )(thr, sl, G)
     w = w[:, 0]
     sw = jnp.sum(w)
@@ -295,6 +298,7 @@ def masked_mean_pallas(G, mask, d_blk: int = 2048, interpret: bool = True):
         out_shape=jax.ShapeDtypeStruct((1, G.shape[1]), jnp.float32),
         compiler_params=_PARALLEL,
         interpret=interpret,
+        name="masked_mean",
     )(w[:, None], G)
     sw = jnp.sum(w)
     return out[0, :d] / jnp.where(sw > 0, sw, 1.0)
@@ -310,7 +314,8 @@ def _order_stat_kernel(g_ref, out_ref, *, m: int, lo: int, hi: int):
     out_ref[...] = acc if hi - lo == 1 else acc / (hi - lo)
 
 
-def _order_stat_pallas(G, lo: int, hi: int, d_blk: int, interpret: bool):
+def _order_stat_pallas(G, lo: int, hi: int, d_blk: int, interpret: bool,
+                       name: str):
     m, d = G.shape
     G, d_blk, grid, _pad = _tiling(G, d_blk)   # zero columns -> 0, sliced off
     out = pl.pallas_call(
@@ -321,6 +326,7 @@ def _order_stat_pallas(G, lo: int, hi: int, d_blk: int, interpret: bool):
         out_shape=jax.ShapeDtypeStruct((1, G.shape[1]), jnp.float32),
         compiler_params=_PARALLEL,
         interpret=interpret,
+        name=name,
     )(G)
     return out[0, :d]
 
@@ -330,7 +336,8 @@ def cwise_median_pallas(G, d_blk: int = 2048, interpret: bool = True):
     two-middle average divides by 2 exactly."""
     m = G.shape[0]
     lo = (m - 1) // 2
-    return _order_stat_pallas(G, lo, m - lo, d_blk, interpret)
+    return _order_stat_pallas(G, lo, m - lo, d_blk, interpret,
+                              "cwise_median")
 
 
 def trimmed_mean_pallas(G, trim_frac: float, d_blk: int = 2048,
@@ -339,4 +346,5 @@ def trimmed_mean_pallas(G, trim_frac: float, d_blk: int = 2048,
     smallest and k largest per dimension, k = ⌊trim_frac·m⌋."""
     m = G.shape[0]
     k = ref.trim_k(trim_frac, m)        # shared degenerate-trim guard
-    return _order_stat_pallas(G, k, m - k, d_blk, interpret)
+    return _order_stat_pallas(G, k, m - k, d_blk, interpret,
+                              "trimmed_mean")

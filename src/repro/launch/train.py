@@ -7,6 +7,10 @@ than one device); on a TPU pod the same driver runs the full config on
 
   PYTHONPATH=src JAX_NUM_CPU_DEVICES=8 python -m repro.launch.train \
       --arch qwen3-0.6b --reduced --steps 20 --attack gaussian --alpha 0.25
+
+Under the JAX profiler each step is a ``train`` step span holding the
+host spans ``feed``, ``dispatch`` (``supervise`` with --supervise),
+``log_sync`` (the metrics' readback), ``telemetry`` and ``checkpoint``.
 """
 from __future__ import annotations
 
@@ -167,61 +171,87 @@ def main(argv=None):
         sup = Supervisor(bundle.step_fn, bcfg, tcfg.recovery, m,
                          ckpt_dir=args.ckpt_dir, like=params,
                          shardings=psh)
-    t_start = time.time()
+    span = jax.profiler.TraceAnnotation
+    tokens_per_step = m * args.batch_per_worker * args.seq
+    t_start = time.perf_counter()
+    t_first = None
     history = []
     with mesh:
         for step in range(args.steps):
-            batch = {k: jax.device_put(jnp.asarray(v), bsh[k])
-                     for k, v in pipe.batch(step).items()}
-            n_active = m
-            if sup is not None:
-                active = sched.active(step)
-                params, opt_state, met = sup.run_step(
-                    params, opt_state, batch, step,
-                    jax.random.fold_in(key, step), sched_active=active)
-                n_active = int(met["n_active"])
-            elif sched is not None:
-                active = sched.active(step)
-                n_active = int(active.sum())
-                params, opt_state, met = bundle.step_fn(
-                    params, opt_state, batch, jnp.int32(step),
-                    jax.random.fold_in(key, step), jnp.asarray(active))
-            else:
-                params, opt_state, met = bundle.step_fn(
-                    params, opt_state, batch, jnp.int32(step),
-                    jax.random.fold_in(key, step))
-            if step % args.log_every == 0 or step == args.steps - 1:
-                met = {k: v if isinstance(v, str) else float(v)
-                       for k, v in met.items()}
-                history.append({"step": step, "n_active": n_active, **met})
-                act_s = f" active={n_active}/{m}" if sched is not None else ""
-                print(f"step {step:4d} loss={met['loss']:.4f} "
-                      f"gnorm={met['gnorm']:.3f} "
-                      f"selected={met['n_selected']:.1f}/{m} "
-                      f"(bucket min {met['n_selected_min']:.0f})" + act_s,
-                      flush=True)
-                if args.ckpt_dir:
-                    # robustness telemetry beside the checkpoints: the
-                    # server surfaces the aggregation stats the weights
-                    # it serves were trained under (serving/telemetry)
-                    telemetry.append_row(args.ckpt_dir, {
-                        "step": step,
-                        "gnorm": met["gnorm"],
-                        "n_selected": met["n_selected"],
-                        "n_selected_min": met["n_selected_min"],
-                        "n_active": met["n_active"],
-                        "quorum": bcfg.quorum or m,
-                    })
-            if (args.ckpt_dir and args.ckpt_every
-                    and (step + 1) % args.ckpt_every == 0):
+            with jax.profiler.StepTraceAnnotation("train", step_num=step):
+                with span("feed"):
+                    batch = {k: jax.device_put(jnp.asarray(v), bsh[k])
+                             for k, v in pipe.batch(step).items()}
+                n_active = m
                 if sup is not None:
-                    sup.checkpoint(params, step + 1)
+                    active = sched.active(step)
+                    with span("supervise"):
+                        params, opt_state, met = sup.run_step(
+                            params, opt_state, batch, step,
+                            jax.random.fold_in(key, step),
+                            sched_active=active)
+                    n_active = int(met["n_active"])
+                elif sched is not None:
+                    active = sched.active(step)
+                    n_active = int(active.sum())
+                    with span("dispatch"):
+                        params, opt_state, met = bundle.step_fn(
+                            params, opt_state, batch, jnp.int32(step),
+                            jax.random.fold_in(key, step),
+                            jnp.asarray(active))
                 else:
-                    ckpt.save(args.ckpt_dir, params, step=step + 1)
+                    with span("dispatch"):
+                        params, opt_state, met = bundle.step_fn(
+                            params, opt_state, batch, jnp.int32(step),
+                            jax.random.fold_in(key, step))
+                if step % args.log_every == 0 or step == args.steps - 1:
+                    with span("log_sync"):
+                        met = {k: v if isinstance(v, str) else float(v)
+                               for k, v in met.items()}
+                    history.append({"step": step, "n_active": n_active,
+                                    **met})
+                    act_s = (f" active={n_active}/{m}" if sched is not None
+                             else "")
+                    print(f"step {step:4d} loss={met['loss']:.4f} "
+                          f"gnorm={met['gnorm']:.3f} "
+                          f"selected={met['n_selected']:.1f}/{m} "
+                          f"(bucket min {met['n_selected_min']:.0f})"
+                          + act_s, flush=True)
+                    if args.ckpt_dir:
+                        # robustness telemetry beside the checkpoints:
+                        # the server surfaces the aggregation stats the
+                        # weights it serves were trained under
+                        # (serving/telemetry)
+                        with span("telemetry"):
+                            telemetry.append_row(args.ckpt_dir, {
+                                "step": step,
+                                "gnorm": met["gnorm"],
+                                "n_selected": met["n_selected"],
+                                "n_selected_min": met["n_selected_min"],
+                                "n_active": met["n_active"],
+                                "quorum": bcfg.quorum or m,
+                            })
+                if (args.ckpt_dir and args.ckpt_every
+                        and (step + 1) % args.ckpt_every == 0):
+                    with span("checkpoint"):
+                        if sup is not None:
+                            sup.checkpoint(params, step + 1)
+                        else:
+                            ckpt.save(args.ckpt_dir, params, step=step + 1)
+            if step == 0:
+                # the first step compiles: timed apart from the rate
+                jax.block_until_ready(params)
+                t_first = time.perf_counter()
 
-    dt = time.time() - t_start
-    tok = args.steps * m * args.batch_per_worker * args.seq
-    print(f"done: {args.steps} steps, {dt:.1f}s, {tok/dt:.0f} tok/s")
+    jax.block_until_ready(params)
+    t_end = time.perf_counter()
+    line = f"done: {args.steps} steps"
+    if t_first is not None:
+        line += f"; first step (compile included) {t_first - t_start:.1f}s"
+    if args.steps > 1:
+        rate = (args.steps - 1) * tokens_per_step / (t_end - t_first)
+        line += f"; steps 1..{args.steps - 1}: {rate:.0f} tok/s"
+    print(line)
     if sup is not None:
         s = sup.summary()
         print(f"supervisor: holds={s['holds']} evictions={s['evictions']} "
@@ -230,10 +260,11 @@ def main(argv=None):
               f"quorum_holds={s['quorum_holds']}")
     if args.ckpt_dir:
         p = pathlib.Path(args.ckpt_dir)
-        if sup is not None:
-            sup.checkpoint(params, args.steps)
-        else:
-            ckpt.save(str(p), params, step=args.steps)
+        with span("checkpoint"):
+            if sup is not None:
+                sup.checkpoint(params, args.steps)
+            else:
+                ckpt.save(str(p), params, step=args.steps)
         (p / "history.json").write_text(json.dumps(history, indent=1))
         print(f"checkpoint -> {p}")
     return history

@@ -236,8 +236,9 @@ def forward(cfg: ModelConfig, params, tokens, prefix_embed=None, remat=False,
         aux = aux + a
     x = L.rms_norm(x, params["final_norm"], cfg.rms_eps)
     head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
-    logits = x @ head
-    logits = shard_hint(logits, P(None, None, "model"))
+    with jax.named_scope("lm_head"):
+        logits = x @ head
+        logits = shard_hint(logits, P(None, None, "model"))
     return logits, aux
 
 
@@ -250,16 +251,17 @@ def loss_fn(cfg: ModelConfig, params, batch, remat=False, seg_hooks=None,
                           seg_hooks, top_hook)
     Pfx = logits.shape[1] - tokens.shape[1]
     # logits at position Pfx+t predict tokens[t+1]
-    pred = logits[:, Pfx:-1]
-    tgt = tokens[:, 1:]
-    logp = jax.nn.log_softmax(pred.astype(jnp.float32), axis=-1)
-    ll = jnp.take_along_axis(logp, tgt[..., None], axis=-1)[..., 0]
-    mask = batch.get("loss_mask")
-    if mask is not None:
-        mask = mask[:, 1:]
-        ce = -jnp.sum(ll * mask) / jnp.maximum(jnp.sum(mask), 1.0)
-    else:
-        ce = -jnp.mean(ll)
+    with jax.named_scope("lm_head"):
+        pred = logits[:, Pfx:-1]
+        tgt = tokens[:, 1:]
+        logp = jax.nn.log_softmax(pred.astype(jnp.float32), axis=-1)
+        ll = jnp.take_along_axis(logp, tgt[..., None], axis=-1)[..., 0]
+        mask = batch.get("loss_mask")
+        if mask is not None:
+            mask = mask[:, 1:]
+            ce = -jnp.sum(ll * mask) / jnp.maximum(jnp.sum(mask), 1.0)
+        else:
+            ce = -jnp.mean(ll)
     return ce + aux, {"ce": ce, "aux": aux}
 
 

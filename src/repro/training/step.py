@@ -189,16 +189,17 @@ def _build_blocked_step(tcfg, mesh, opt, layout):
                          for k, b in barriers.items()}
                 top_hook = lambda p: top_barrier(
                     p, toks["top"], jnp.float32(0), keyf)
-            loss, met = TF.loss_fn(cfg, params, lbatch, remat=remat,
-                                   seg_hooks=hooks, top_hook=top_hook)
-            if guard:
-                # fault injection rides the LOSS inside the
-                # differentiated function: autodiff propagates the NaN
-                # into this worker's entire gradient, exactly like a
-                # real fp blow-up on the device would
-                f = faultf[jax.lax.axis_index(waxes)]
-                loss = loss * jnp.where(f > 0, jnp.float32(jnp.nan),
-                                        jnp.float32(1.0))
+            with jax.named_scope("loss"):
+                loss, met = TF.loss_fn(cfg, params, lbatch, remat=remat,
+                                       seg_hooks=hooks, top_hook=top_hook)
+                if guard:
+                    # fault injection rides the LOSS inside the
+                    # differentiated function: autodiff propagates the
+                    # NaN into this worker's entire gradient, exactly
+                    # like a real fp blow-up on the device would
+                    f = faultf[jax.lax.axis_index(waxes)]
+                    loss = loss * jnp.where(f > 0, jnp.float32(jnp.nan),
+                                            jnp.float32(1.0))
             return loss, met
 
         (loss, met), (agg, tgrads) = jax.value_and_grad(
@@ -208,21 +209,23 @@ def _build_blocked_step(tcfg, mesh, opt, layout):
         # scan iterations into one histogram over counts 0..m
         sel_hist = sum(jax.tree.leaves(tgrads))
 
-        new_params, new_opt = opt.update(agg, opt_state, params, step_idx)
-        # fsdp-sharded leaves need a cross-worker psum; replicated
-        # leaves are already global.
-        from ..core.blocked import _fsdp_dim
-        ss_f = jnp.float32(0)
-        ss_r = jnp.float32(0)
-        for g, s in zip(jax.tree.leaves(agg),
-                        jax.tree.leaves(pspecs,
-                                        is_leaf=lambda x: isinstance(x, P))):
-            ss = jnp.sum(jnp.square(g.astype(jnp.float32)))
-            if _fsdp_dim(s, waxes) is not None:
-                ss_f += ss
-            else:
-                ss_r += ss
-        gnorm = jnp.sqrt(jax.lax.psum(ss_f, waxes) + ss_r)
+        with jax.named_scope("optimizer"):
+            new_params, new_opt = opt.update(agg, opt_state, params,
+                                             step_idx)
+            # fsdp-sharded leaves need a cross-worker psum; replicated
+            # leaves are already global.
+            from ..core.blocked import _fsdp_dim
+            ss_f = jnp.float32(0)
+            ss_r = jnp.float32(0)
+            for g, s in zip(jax.tree.leaves(agg),
+                            jax.tree.leaves(
+                                pspecs, is_leaf=lambda x: isinstance(x, P))):
+                ss = jnp.sum(jnp.square(g.astype(jnp.float32)))
+                if _fsdp_dim(s, waxes) is not None:
+                    ss_f += ss
+                else:
+                    ss_r += ss
+            gnorm = jnp.sqrt(jax.lax.psum(ss_f, waxes) + ss_r)
         # stats were psum'd before the (replicated) selection, so the
         # histogram is identical on every worker — no further
         # cross-worker reduction needed
@@ -326,16 +329,18 @@ def _build_global_step(tcfg, mesh, opt, layout):
             # a fully-NaN per-worker gradient — a faithful stand-in
             # for an fp blow-up on that worker's device
             def wloss(p, wbatch, f):
-                loss, met = TF.loss_fn(cfg, p, wbatch, remat=remat)
-                return loss * jnp.where(f > 0, jnp.float32(jnp.nan),
-                                        jnp.float32(1.0)), met
+                with jax.named_scope("loss"):
+                    loss, met = TF.loss_fn(cfg, p, wbatch, remat=remat)
+                    return loss * jnp.where(f > 0, jnp.float32(jnp.nan),
+                                            jnp.float32(1.0)), met
 
             (loss, met), grads = jax.vmap(
                 jax.value_and_grad(wloss, has_aux=True),
                 in_axes=(None, 0, 0))(params, batch, faultf)
         else:
             def wloss(p, wbatch):
-                return TF.loss_fn(cfg, p, wbatch, remat=remat)
+                with jax.named_scope("loss"):
+                    return TF.loss_fn(cfg, p, wbatch, remat=remat)
 
             (loss, met), grads = jax.vmap(
                 jax.value_and_grad(wloss, has_aux=True),
@@ -346,11 +351,14 @@ def _build_global_step(tcfg, mesh, opt, layout):
             lambda g, s: jax.lax.with_sharding_constraint(
                 g, NamedSharding(mesh, P(wspec, *s))),
             grads, pspecs, is_leaf=is_pspec)
-        agg, n_sel = agg_region(grads, key,
-                                *((activef,) if elastic else ()))
-        new_params, new_opt = opt.update(agg, opt_state, params, step_idx)
-        gnorm = jnp.sqrt(sum(jnp.sum(jnp.square(g.astype(jnp.float32)))
-                             for g in jax.tree.leaves(agg)))
+        with jax.named_scope("aggregate"):
+            agg, n_sel = agg_region(grads, key,
+                                    *((activef,) if elastic else ()))
+        with jax.named_scope("optimizer"):
+            new_params, new_opt = opt.update(agg, opt_state, params,
+                                             step_idx)
+            gnorm = jnp.sqrt(sum(jnp.sum(jnp.square(g.astype(jnp.float32)))
+                                 for g in jax.tree.leaves(agg)))
         if guard:
             # active-masked finite means + the per-worker finiteness
             # vector (the supervisor's eviction signal); exact

@@ -69,3 +69,26 @@ def test_kernel_compiles_for_v5e(one_chip, kernel, m, d):
     G = jax.ShapeDtypeStruct((m, d), jnp.float32, sharding=one_chip)
     compiled = jax.jit(KERNELS[kernel]).lower(G).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_bucket_round_names_its_kernels(one_chip, monkeypatch):
+    """The BrSGD round over one qwen3-0.6b layer's bucket, compiled as a
+    TPU process runs it, holds exactly one instruction named after each
+    of its two Pallas passes, each the kernel's custom call: the names
+    the benchmark's kernel readers match in the chip trace."""
+    import re
+
+    from repro.configs.base import ByzantineConfig
+    from repro.core import engine
+    from repro.kernels import ops
+    monkeypatch.setattr(ops, "_INTERPRET", False)
+    monkeypatch.setattr(ops, "_USE_PALLAS_DEFAULT", True)
+    G = jax.ShapeDtypeStruct((8, 15_730_944), jnp.bfloat16, sharding=one_chip)
+    cfg = ByzantineConfig(aggregator="brsgd", beta=0.5)
+    text = jax.jit(lambda g: engine.aggregate_local(g, cfg)).lower(G) \
+        .compile().as_text()
+    instrs = re.findall(r"^\s*(?:ROOT )?%([\w.\-]+) = .*?\s([\w\-]+)\(", text,
+                        re.M)
+    for kernel in ("fused_stats", "select_mean"):
+        hits = [(n, op) for n, op in instrs if kernel in n]
+        assert len(hits) == 1 and hits[0][1] == "custom-call", (kernel, hits)
