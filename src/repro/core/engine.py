@@ -566,7 +566,7 @@ def expected_collectives(spec: AggregatorSpec, layout: str, n_leaves: int,
 # local executor — single-host G [m, d]
 # ---------------------------------------------------------------------------
 
-def _combine_rows(G, w, use_pallas: bool, d_blk: int):
+def _combine_rows(G, w, use_pallas: bool, d_blk: int | None):
     """Σ_i w_i g_i / Σ_i w_i.  The jnp path accumulates rows in a fixed
     sequential order (ref.masked_mean_det) so results are reproducible
     and mean-degenerate cases are bit-exact; the Pallas path streams G
@@ -578,8 +578,8 @@ def _combine_rows(G, w, use_pallas: bool, d_blk: int):
 
 def aggregate_local(G, cfg: ByzantineConfig, use_pallas: bool | None = None,
                     return_state: bool = False,
-                    spec: AggregatorSpec | None = None, d_blk: int = 2048,
-                    valid=None):
+                    spec: AggregatorSpec | None = None,
+                    d_blk: int | None = None, valid=None):
     """Run one aggregator on the worker-gradient matrix G [m, d] -> [d].
 
     ``valid`` ([m] 0/1) runs the elastic masked variant: statistics,
@@ -614,11 +614,13 @@ def aggregate_local(G, cfg: ByzantineConfig, use_pallas: bool | None = None,
         # median/mean HBM writes), pass 2 fuses selection + masked mean
         # — G is streamed from HBM exactly twice.
         scores, l1 = ops.brsgd_partials(G, use_pallas=True, d_blk=d_blk)
-        agg, _w = ops.brsgd_select_mean(G, scores, l1, cfg.beta,
-                                        cfg.threshold, use_pallas=True,
-                                        d_blk=d_blk)
+        agg, w = ops.brsgd_select_combine(G, scores, l1, cfg.beta,
+                                          cfg.threshold, use_pallas=True,
+                                          d_blk=d_blk)
         if return_state:
-            return agg, brsgd_select(scores, l1, cfg.beta, cfg.threshold)
+            # the kernel's own selection: no second one on the device
+            st = brsgd_select(scores, l1, cfg.beta, cfg.threshold)
+            return agg, st._replace(selected=w > 0)
         return agg
 
     stats = leaf_stats(G.astype(jnp.float32), spec.stats, m, use_pallas=up)

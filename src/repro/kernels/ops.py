@@ -4,6 +4,10 @@ On TPU the Pallas kernels run compiled (interpret=False); everywhere
 else (this CPU container, unit tests) they run in interpret mode or
 fall back to the jnp reference — selected once at import.  Both paths
 are numerically validated against ref.py in tests/test_kernels.py.
+
+``d_blk`` is None everywhere but in tests: the kernels then derive their
+column block from G's shape (``brsgd_stats._tiling``); a number forces
+that block, to exercise many blocks and ragged tails at small widths.
 """
 from __future__ import annotations
 
@@ -32,7 +36,8 @@ def default_use_pallas() -> bool:
 
 
 @functools.partial(jax.jit, static_argnames=("use_pallas", "d_blk"))
-def brsgd_stats(G, use_pallas: bool = _USE_PALLAS_DEFAULT, d_blk: int = 2048):
+def brsgd_stats(G, use_pallas: bool = _USE_PALLAS_DEFAULT,
+                d_blk: int | None = None):
     """G [m,d] -> (median [d], mean [d], scores [m], l1 [m])."""
     if use_pallas:
         return brsgd_stats_pallas(G, d_blk=d_blk, interpret=_INTERPRET)
@@ -43,7 +48,7 @@ def brsgd_stats(G, use_pallas: bool = _USE_PALLAS_DEFAULT, d_blk: int = 2048):
                                              "d_blk"))
 def fused_stats(G, needs: tuple, axis: int = 0,
                 use_pallas: bool = _USE_PALLAS_DEFAULT,
-                d_blk: int = 2048, valid=None, rows=None,
+                d_blk: int | None = None, valid=None, rows=None,
                 refs=None) -> dict:
     """Fused statistics pass: any subset of ``ref.STAT_NAMES`` from one
     read of G (DESIGN.md §Perf).
@@ -84,7 +89,7 @@ def masked_stat_refs(G, needs: tuple, valid, axis: int = 0) -> dict:
 
 @functools.partial(jax.jit, static_argnames=("use_pallas", "d_blk"))
 def brsgd_partials(G, use_pallas: bool = _USE_PALLAS_DEFAULT,
-                   d_blk: int = 2048):
+                   d_blk: int | None = None):
     """G [m,d] -> (scores [m], l1 [m]) — the stats pass without the
     [d]-sized median/mean outputs (first pass of the fused BrSGD path)."""
     st = fused_stats(G, ("scores", "l1"), use_pallas=use_pallas, d_blk=d_blk)
@@ -92,9 +97,9 @@ def brsgd_partials(G, use_pallas: bool = _USE_PALLAS_DEFAULT,
 
 
 @functools.partial(jax.jit, static_argnames=("beta", "use_pallas", "d_blk"))
-def brsgd_select_mean(G, scores, l1, beta: float, threshold,
-                      use_pallas: bool = _USE_PALLAS_DEFAULT,
-                      d_blk: int = 2048):
+def brsgd_select_combine(G, scores, l1, beta: float, threshold,
+                         use_pallas: bool = _USE_PALLAS_DEFAULT,
+                         d_blk: int | None = None):
     """Fused C1∩C2 selection + masked mean (second pass of the fused
     BrSGD path).  Returns (aggregate [d], selection weights [m])."""
     if use_pallas:
@@ -108,7 +113,7 @@ def brsgd_select_mean(G, scores, l1, beta: float, threshold,
 
 @functools.partial(jax.jit, static_argnames=("use_pallas", "d_blk"))
 def masked_mean(G, mask, use_pallas: bool = _USE_PALLAS_DEFAULT,
-                d_blk: int = 2048):
+                d_blk: int | None = None):
     """Masked (bool) or weighted (f32) row mean: Σ w_i g_i / Σ w_i."""
     if use_pallas:
         return masked_mean_pallas(G, mask, d_blk=d_blk, interpret=_INTERPRET)
@@ -116,8 +121,8 @@ def masked_mean(G, mask, use_pallas: bool = _USE_PALLAS_DEFAULT,
 
 
 @functools.partial(jax.jit, static_argnames=("use_pallas", "d_blk"))
-def cwise_median(G, use_pallas: bool = _USE_PALLAS_DEFAULT, d_blk: int = 2048,
-                 valid=None):
+def cwise_median(G, use_pallas: bool = _USE_PALLAS_DEFAULT,
+                 d_blk: int | None = None, valid=None):
     if valid is not None:
         return ref.masked_cwise_median_ref(G, valid)
     if use_pallas:
@@ -128,7 +133,7 @@ def cwise_median(G, use_pallas: bool = _USE_PALLAS_DEFAULT, d_blk: int = 2048,
 @functools.partial(jax.jit, static_argnames=("trim_frac", "use_pallas",
                                              "d_blk"))
 def trimmed_mean(G, trim_frac: float, use_pallas: bool = _USE_PALLAS_DEFAULT,
-                 d_blk: int = 2048, valid=None):
+                 d_blk: int | None = None, valid=None):
     """Coordinate-wise trimmed mean (k = ⌊trim_frac·m⌋ per side; with a
     ``valid`` mask both counts are over the active rows, traced)."""
     if valid is not None:

@@ -57,9 +57,9 @@ def test_fused_ref_every_subset_matches_per_stat_oracles(rng, needs):
 @pytest.mark.parametrize("needs", SUBSETS,
                          ids=["+".join(s) for s in SUBSETS])
 def test_fused_pallas_every_subset_matches_ref(rng, needs):
-    """The one-HBM-read kernel == the one-sort reference, through the
-    zero-pad path (d % d_blk != 0: pad columns score +1 per worker and
-    contribute 0 to l1/d2med/gram)."""
+    """The one-HBM-read kernel == the one-sort reference, through a
+    ragged block (1,024 columns holding G's 130: the garbage past d is
+    zeroed and masked, so it adds nothing to any statistic)."""
     m, d = 7, 130
     G = jnp.asarray((rng.normal(size=(m, d)) * 3).astype("f4"))
     got = fused_stats_pallas(G, needs, d_blk=64)
@@ -67,10 +67,34 @@ def test_fused_pallas_every_subset_matches_ref(rng, needs):
     for k in needs:
         np.testing.assert_allclose(np.asarray(got[k]), np.asarray(want[k]),
                                    rtol=1e-4, atol=1e-4, err_msg=k)
-    # scores are 0/1 sums: integer-exact through the padding correction
+    # scores are 0/1 sums: integer-exact through the ragged block
     if "scores" in needs:
         np.testing.assert_array_equal(np.asarray(got["scores"]),
                                       np.asarray(want["scores"]))
+
+
+@pytest.mark.parametrize("d", [2047, 2048, 2049, 2176, 3000])
+def test_fused_pallas_ragged_widths_every_subset(rng, d):
+    """Blocks of 1,024: just under, at and just over two blocks, a tail
+    of whole lanes, and one of 952 columns; constant columns (every
+    worker scores 1 there) among them, the last one included.  Each
+    statistic alone and all four together (every subset: above)."""
+    m = 8
+    G = (rng.normal(size=(m, d)) * 3).astype("f4")
+    G[:, ::5] = 0.75
+    G[:, -1] = 2.0
+    G = jnp.asarray(G)
+    for needs in [(n,) for n in ref.STAT_NAMES] + [ref.STAT_NAMES]:
+        got = fused_stats_pallas(G, needs, d_blk=1024)
+        want = ref.fused_stats_ref(G, needs)
+        for k in needs:
+            if k == "scores":
+                np.testing.assert_array_equal(np.asarray(got[k]),
+                                              np.asarray(want[k]))
+            else:
+                np.testing.assert_allclose(np.asarray(got[k]),
+                                           np.asarray(want[k]),
+                                           rtol=1e-4, atol=1e-4, err_msg=k)
 
 
 def test_ops_fused_stats_dispatch_parity(rng):

@@ -6,9 +6,11 @@ import numpy as np
 import pytest
 
 from repro.kernels import ops, ref
-from repro.kernels.brsgd_stats import (brsgd_partials_pallas,
+from repro.kernels.brsgd_stats import (_BLOCK_BYTES, _VMEM_BUDGET, _tiling,
+                                       brsgd_partials_pallas,
                                        brsgd_stats_pallas,
                                        cwise_median_pallas,
+                                       fused_stats_pallas,
                                        masked_mean_pallas,
                                        select_mean_pallas,
                                        trimmed_mean_pallas)
@@ -59,10 +61,13 @@ def test_cwise_median_kernel_odd_even_workers(m):
 
 
 def test_kernel_blocking_invariance():
-    """Different d_blk tilings give identical results."""
+    """Different d_blk tilings give identical results, down to the
+    derived block (None: 21,504 columns at m = 12 f32, three blocks and
+    a ragged fourth)."""
     rng = np.random.default_rng(7)
-    G = jnp.asarray(rng.normal(size=(12, 1000)).astype("f4"))
-    outs = [brsgd_stats_pallas(G, d_blk=b) for b in (64, 256, 1000, 4096)]
+    G = jnp.asarray(rng.normal(size=(12, 70_000)).astype("f4"))
+    outs = [brsgd_stats_pallas(G, d_blk=b)
+            for b in (64, 256, 1000, 4096, 32768, None)]
     for o in outs[1:]:
         for a, b in zip(outs[0], o):
             # different tilings reduce in different orders -> f32 rounding
@@ -194,9 +199,120 @@ def test_masked_mean_float_weights():
 
 
 def test_score_constant_column_counts_everyone():
-    """A constant column splits into {all >= mean}: everyone scores 1 —
-    guards the zero-padding correction in the kernel wrapper."""
+    """A constant column splits into {all >= mean}: everyone scores 1
+    (the ragged-block cases below hold constant columns too)."""
     G = jnp.ones((6, 10))
     _, _, sc, l1 = brsgd_stats_pallas(G, d_blk=4)   # forces padding
     np.testing.assert_array_equal(np.asarray(sc), np.full(6, 10.0))
     np.testing.assert_allclose(np.asarray(l1), np.zeros(6), atol=1e-6)
+
+
+RAGGED_KERNELS = ("brsgd_stats", "fused_stats", "select_mean", "masked_mean",
+                  "cwise_median", "trimmed_mean")
+
+
+def _kernel_vs_ref(kernel, G, d_blk):
+    """[(what, got, want, exact)] of one kernel against the jnp oracle."""
+    if kernel == "brsgd_stats":
+        got = brsgd_stats_pallas(G, d_blk=d_blk)
+        want = ref.brsgd_stats_ref(G)
+        return [(n, a, b, n in ("median", "scores")) for n, a, b in
+                zip(("median", "mean", "scores", "l1"), got, want)]
+    if kernel == "fused_stats":
+        got = fused_stats_pallas(G, ref.STAT_NAMES, d_blk=d_blk)
+        want = ref.fused_stats_ref(G, ref.STAT_NAMES)
+        return [(n, got[n], want[n], n == "scores") for n in ref.STAT_NAMES]
+    if kernel == "select_mean":
+        from repro.core.engine import brsgd_select
+        scores, l1 = brsgd_partials_pallas(G, d_blk=d_blk)
+        agg, w = select_mean_pallas(G, scores, l1, 0.5, 0.0, d_blk=d_blk)
+        sel = brsgd_select(scores, l1, 0.5, 0.0).selected
+        return [("weights", w, sel.astype(jnp.float32), True),
+                ("aggregate", agg, ref.masked_mean_ref(G, sel), False)]
+    if kernel == "masked_mean":
+        mask = jnp.arange(G.shape[0]) % 3 != 1
+        return [("mean", masked_mean_pallas(G, mask, d_blk=d_blk),
+                 ref.masked_mean_ref(G, mask), False)]
+    if kernel == "cwise_median":
+        return [("median", cwise_median_pallas(G, d_blk=d_blk),
+                 ref.cwise_median_ref(G), True)]
+    return [("trimmed", trimmed_mean_pallas(G, 0.25, d_blk=d_blk),
+             ref.trimmed_mean_ref(G, 0.25), False)]
+
+
+def _assert_kernel_matches_ref(kernel, G, d_blk, atol):
+    for what, got, want, exact in _kernel_vs_ref(kernel, G, d_blk):
+        if exact:
+            np.testing.assert_array_equal(np.asarray(got), np.asarray(want),
+                                          err_msg=what)
+        else:
+            np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                       rtol=1e-4, atol=atol, err_msg=what)
+
+
+# (d_blk, d): just under, at and just over a whole number of blocks, a
+# tail of whole lanes, tails of whole and partial chunks (4096: chunks of
+# 4,096), and one block wider than G
+RAGGED = [(1024, 3071), (1024, 3072), (1024, 3073), (1024, 3200),
+          (4096, 8191), (4096, 9692), (None, 1000)]
+
+
+@pytest.mark.parametrize("d_blk,d", RAGGED)
+@pytest.mark.parametrize("m", [5, 8])
+@pytest.mark.parametrize("kernel", RAGGED_KERNELS)
+def test_kernel_ragged_last_block_vs_ref(kernel, m, d_blk, d):
+    """Every kernel that shares ``_tiling`` keeps the garbage past d in
+    a ragged last block out of its results: scores, weights and medians
+    exact, sums to f32 rounding.  Every seventh column, and the last, is
+    constant (all workers tie: everyone scores 1 there)."""
+    rng = np.random.default_rng(m * 10_000 + d)
+    G = rng.normal(size=(m, d)).astype("f4") * 3
+    G[:, ::7] = 1.25
+    G[:, -1] = -0.5
+    _assert_kernel_matches_ref(kernel, jnp.asarray(G), d_blk, 1e-4)
+
+
+@pytest.mark.parametrize("d", [65_535, 65_536, 65_537])
+@pytest.mark.parametrize("kernel", RAGGED_KERNELS)
+def test_kernel_derived_block_ragged_vs_ref(kernel, d):
+    """The same at the derived block (32,768 columns at m = 8 f32): d
+    just under, at and just over two blocks."""
+    rng = np.random.default_rng(d)
+    G = rng.normal(size=(8, d)).astype("f4")
+    G[:, ::7] = 1.25
+    G = jnp.asarray(G)
+    assert _tiling(G, None, 1)[:2] == (32_768, -(-d // 32_768))
+    _assert_kernel_matches_ref(kernel, G, None, 1e-3)
+
+
+@pytest.mark.parametrize("m,dtype", [(8, jnp.bfloat16), (8, jnp.float32),
+                                     (2, jnp.float32), (64, jnp.float32),
+                                     (4, jnp.bfloat16), (20, jnp.bfloat16)])
+@pytest.mark.parametrize("row_outs", [0, 1, 2])
+def test_tiling_derives_block_from_shape(m, dtype, row_outs):
+    """The block follows m, the dtype and the kernel's VMEM need: about
+    1 MiB of G per grid step, within the VMEM budget, whole chunks."""
+    d = 15_730_944
+    G = jax.ShapeDtypeStruct((m, d), dtype)
+    block, grid, chunk = _tiling(G, None, row_outs)
+    item = jnp.dtype(dtype).itemsize
+    rows = -(-m * item // 32) * 32 // item
+    assert grid == -(-d // block) and block % chunk == 0
+    assert 2 * (rows * item + row_outs * 4) * block <= _VMEM_BUDGET
+    assert _BLOCK_BYTES // 4 <= m * item * block <= _BLOCK_BYTES
+    if (m, dtype) == (8, jnp.bfloat16):
+        assert (block, grid) == (65_536, 241)
+
+
+@pytest.mark.parametrize("d_blk,d,want", [(64, 3000, (1024, 3)),
+                                          (1000, 1000, (1024, 1)),
+                                          (4096, 100, (1024, 1)),
+                                          (None, 1000, (1024, 1)),
+                                          (None, 70_000, (32_768, 3))])
+def test_tiling_override_and_single_block(d_blk, d, want):
+    """Blocks are whole 1,024-column groups: an override rounds up to
+    them, and a leaf narrower than one block is one block of d rounded
+    up to them."""
+    G = jax.ShapeDtypeStruct((8, d), jnp.float32)
+    block, grid, chunk = _tiling(G, d_blk, 1)
+    assert (block, grid) == want and block % chunk == 0
