@@ -1,0 +1,9 @@
+"""Device time per training step of DeepSeek-V2's MoE FFNs, in ms: the
+ops under the program's ``moe`` scope (router, dispatch, held experts,
+shared experts), forward, backward and recomputation
+(``bench/scopes_dsv2.py``)."""
+from bench import scopes_dsv2
+
+
+def read(ctx):
+    return scopes_dsv2.read(ctx, "moe")

@@ -12,6 +12,22 @@ from typing import Optional, Tuple
 
 
 @dataclass(frozen=True)
+class YarnSpec:
+    """YaRN rotary scaling (arXiv:2309.00071), as DeepSeek-V2 states it:
+    the rotary frequencies blend their plain and ``factor``-stretched
+    values along a linear ramp between the correction dimensions of
+    ``beta_fast`` and ``beta_slow``, and the softmax scale and the
+    cos/sin tables take the attention-temperature factor ``mscale``."""
+
+    factor: float = 1.0
+    original_max_position: int = 4096
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
+
+
+@dataclass(frozen=True)
 class AttentionSpec:
     """Attention family description.
 
@@ -27,6 +43,11 @@ class AttentionSpec:
     head_dim: int = 128
     qk_norm: bool = False
     rope_theta: float = 10_000.0
+    # None = plain RoPE; a YarnSpec stretches the rotary frequencies
+    rope_scaling: Optional[YarnSpec] = None
+    # DeepSeek-V2 pair layout: each rope head is de-interleaved
+    # (pairs (2i, 2i+1) become (i, i + D/2)) before rotate_half
+    rope_interleaved: bool = False
     # sliding window (tokens); 0 = full attention.  The long_500k shape
     # auto-selects window attention for full-attention archs.
     window: int = 0
@@ -40,13 +61,27 @@ class AttentionSpec:
 
 @dataclass(frozen=True)
 class MoESpec:
-    n_experts: int = 0            # 0 = dense FFN
+    n_experts: int = 0            # routed experts the router scores; 0 = dense
     top_k: int = 1
     n_shared: int = 0             # shared (always-on) experts
     d_ff_expert: int = 0          # per-expert hidden size
-    capacity_factor: float = 1.25
-    router_zloss: float = 1e-3
-    aux_loss: float = 1e-2
+    # top-k weights renormalised to sum to 1, then scaled
+    norm_topk_prob: bool = True
+    routed_scaling_factor: float = 1.0
+    # balance loss: "switch" (Switch load balance + router z-loss) or
+    # "seq" (DeepSeek-V2's sequence-wise balance loss, no z-loss)
+    balance: str = "switch"
+    aux_loss: float = 1e-2        # the balance loss's alpha
+    router_zloss: float = 1e-3    # "switch" only
+    # the experts this layer holds: [expert_first, expert_first + n_held)
+    # of n_experts (expert parallelism: every chip that shares a layer
+    # holds its own range); n_held 0 = all of them
+    expert_first: int = 0
+    n_held: int = 0
+
+    @property
+    def held(self) -> int:
+        return self.n_held or self.n_experts
 
 
 @dataclass(frozen=True)
@@ -136,6 +171,8 @@ class ModelConfig:
                 moe,
                 n_experts=min(moe.n_experts, 4),
                 top_k=min(moe.top_k, 2),
+                expert_first=0,
+                n_held=min(moe.n_held, 4),
                 n_shared=min(moe.n_shared, 1),
                 d_ff_expert=min(moe.d_ff_expert, 128),
             )

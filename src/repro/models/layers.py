@@ -6,13 +6,14 @@ All functions are pure; parameters come in as pytrees declared by the
 """
 from __future__ import annotations
 
+import math
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..configs.base import AttentionSpec, ModelConfig
+from ..configs.base import AttentionSpec, ModelConfig, YarnSpec
 from .params import ParamDef
 
 NEG_INF = -1e30
@@ -40,17 +41,58 @@ def activate(x, kind: str):
     raise ValueError(f"unknown activation {kind!r}")
 
 
-def rope_freqs(head_dim: int, theta: float):
-    return 1.0 / (theta ** (jnp.arange(0, head_dim, 2, dtype=jnp.float32) / head_dim))
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """YaRN's attention-temperature factor: 0.1·mscale·ln(factor) + 1."""
+    return 1.0 if factor <= 1.0 else 0.1 * mscale * math.log(factor) + 1.0
 
 
-def apply_rope(x, positions, theta: float):
-    """x: [..., S, H, D] (D even), positions: [..., S]."""
+def _yarn_correction_range(y: YarnSpec, dim: int, theta: float):
+    """The rotary dimensions (as pair indices) between which YaRN ramps
+    from plain to interpolated frequencies: the pairs that turn
+    ``beta_fast`` and ``beta_slow`` times over the original length."""
+    def corr(n_rot):
+        return (dim * math.log(y.original_max_position / (n_rot * 2 * math.pi))
+                / (2 * math.log(theta)))
+    lo = math.floor(corr(y.beta_fast))
+    hi = math.ceil(corr(y.beta_slow))
+    return max(lo, 0), min(hi, dim - 1)
+
+
+def rope_freqs(head_dim: int, theta: float, scaling: Optional[YarnSpec] = None):
+    if scaling is None:
+        return 1.0 / (theta ** (jnp.arange(0, head_dim, 2, dtype=jnp.float32) / head_dim))
+    # YaRN: high frequencies (below the ramp) keep their plain value, low
+    # ones (above it) are divided by the factor, linear blend between
+    base = theta ** (np.arange(0, head_dim, 2, dtype=np.float64) / head_dim)
+    lo, hi = _yarn_correction_range(scaling, head_dim, theta)
+    ramp = np.clip((np.arange(head_dim // 2) - lo) / max(hi - lo, 0.001), 0.0, 1.0)
+    keep = 1.0 - ramp
+    inv = (1.0 / (scaling.factor * base)) * (1.0 - keep) + (1.0 / base) * keep
+    return jnp.asarray(inv, jnp.float32)
+
+
+def apply_rope(x, positions, theta: float, scaling: Optional[YarnSpec] = None,
+               interleaved: bool = False):
+    """x: [..., S, H, D] (D even), positions: [..., S].
+
+    ``interleaved`` takes the rotated pairs as (2i, 2i+1) and returns
+    them de-interleaved, (i, i + D/2): DeepSeek-V2's layout.  Queries and
+    keys go through the same map, so their products are those of the
+    published model."""
     d = x.shape[-1]
-    inv = rope_freqs(d, theta)                       # [D/2]
+    inv = rope_freqs(d, theta, scaling)              # [D/2]
     ang = positions[..., None].astype(jnp.float32) * inv     # [..., S, D/2]
     cos, sin = jnp.cos(ang)[..., None, :], jnp.sin(ang)[..., None, :]
-    x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
+    if scaling is not None:
+        f = (yarn_mscale(scaling.factor, scaling.mscale)
+             / yarn_mscale(scaling.factor, scaling.mscale_all_dim))
+        if f != 1.0:
+            cos, sin = cos * f, sin * f
+    xf = x.astype(jnp.float32)
+    if interleaved:
+        x1, x2 = xf[..., 0::2], xf[..., 1::2]
+    else:
+        x1, x2 = jnp.split(xf, 2, axis=-1)
     out = jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1)
     return out.astype(x.dtype)
 
@@ -217,29 +259,46 @@ def mla_defs(d_model: int, a: AttentionSpec) -> dict:
     return d
 
 
-def _mla_q(p, a: AttentionSpec, x, positions):
+def _mla_rope(a: AttentionSpec, x, positions):
+    return apply_rope(x, positions, a.rope_theta, a.rope_scaling,
+                      a.rope_interleaved)
+
+
+def mla_softmax_scale(a: AttentionSpec):
+    """(qk_nope + qk_rope)^-1/2, times YaRN's mscale(mscale_all_dim)^2
+    where the rotary embedding is YaRN-scaled."""
+    scale = 1.0 / jnp.sqrt(a.qk_nope_dim + a.qk_rope_dim)
+    y = a.rope_scaling
+    if y is not None and y.mscale_all_dim:
+        m = yarn_mscale(y.factor, y.mscale_all_dim)
+        scale = scale * (m * m)
+    return scale
+
+
+def _mla_q(p, a: AttentionSpec, x, positions, eps):
     if a.q_lora_rank:
-        cq = rms_norm(x @ p["w_dq"], p["q_norm"])
+        cq = rms_norm(x @ p["w_dq"], p["q_norm"], eps)
         q = jnp.einsum("bsr,rhk->bshk", cq, p["w_uq"])
     else:
         q = jnp.einsum("bsd,dhk->bshk", x, p["w_uq"])
     q_nope, q_rope = q[..., :a.qk_nope_dim], q[..., a.qk_nope_dim:]
-    q_rope = apply_rope(q_rope, positions, a.rope_theta)
+    q_rope = _mla_rope(a, q_rope, positions)
     return q_nope, q_rope
 
 
-def mla_attention(p, a: AttentionSpec, x, positions, mask=None):
-    """Full-sequence MLA.  Returns output and the latent cache pieces."""
+def mla_attention(p, a: AttentionSpec, x, positions, mask=None, eps=1e-5):
+    """Full-sequence MLA.  Returns output and the latent cache pieces.
+    ``eps`` is the model's RMS-norm epsilon (the latent norms')."""
     B, S, _ = x.shape
     if positions.ndim == 1:
         positions = positions[None, :]
-    q_nope, q_rope = _mla_q(p, a, x, positions)
-    c_kv = rms_norm(x @ p["w_dkv"], p["kv_norm"])           # [B,S,R]
-    k_rope = apply_rope((x @ p["w_krope"])[:, :, None, :], positions,
-                        a.rope_theta)                        # [B,S,1,Dr]
+    q_nope, q_rope = _mla_q(p, a, x, positions, eps)
+    c_kv = rms_norm(x @ p["w_dkv"], p["kv_norm"], eps)      # [B,S,R]
+    k_rope = _mla_rope(a, (x @ p["w_krope"])[:, :, None, :],
+                       positions)                            # [B,S,1,Dr]
     k_nope = jnp.einsum("bsr,rhk->bshk", c_kv, p["w_uk"])
     v = jnp.einsum("bsr,rhk->bshk", c_kv, p["w_uv"])
-    scale = 1.0 / jnp.sqrt(a.qk_nope_dim + a.qk_rope_dim)
+    scale = mla_softmax_scale(a)
     k_rope_sq = k_rope.squeeze(2)                            # [B,S,Dr]
     logits = (jnp.einsum("bshk,bthk->bhst", q_nope, k_nope) +
               jnp.einsum("bshk,btk->bhst", q_rope, k_rope_sq)
@@ -252,7 +311,7 @@ def mla_attention(p, a: AttentionSpec, x, positions, mask=None):
     return jnp.einsum("bshk,hkd->bsd", out, p["wo"]), (c_kv, k_rope.squeeze(2))
 
 
-def mla_decode(p, a: AttentionSpec, x, cache_c, cache_kr, pos):
+def mla_decode(p, a: AttentionSpec, x, cache_c, cache_kr, pos, eps=1e-5):
     """Weight-absorbed single-token MLA decode.
 
     cache_c: [B,T,R] latent; cache_kr: [B,T,Dr] rope key; ``pos``:
@@ -264,14 +323,14 @@ def mla_decode(p, a: AttentionSpec, x, cache_c, cache_kr, pos):
     B = x.shape[0]
     posb = _decode_pos(pos, B)
     posv = posb[:, None]                                     # [B,1]
-    q_nope, q_rope = _mla_q(p, a, x, posv)                   # [B,1,H,*]
-    c_new = rms_norm(x @ p["w_dkv"], p["kv_norm"])           # [B,1,R]
-    kr_new = apply_rope((x @ p["w_krope"])[:, :, None, :], posv,
-                        a.rope_theta).squeeze(2)             # [B,1,Dr]
+    q_nope, q_rope = _mla_q(p, a, x, posv, eps)              # [B,1,H,*]
+    c_new = rms_norm(x @ p["w_dkv"], p["kv_norm"], eps)      # [B,1,R]
+    kr_new = _mla_rope(a, (x @ p["w_krope"])[:, :, None, :],
+                       posv).squeeze(2)                      # [B,1,Dr]
     cache_c = cache_c.at[jnp.arange(B), posb].set(c_new[:, 0])
     cache_kr = cache_kr.at[jnp.arange(B), posb].set(kr_new[:, 0])
     q_abs = jnp.einsum("bshk,rhk->bshr", q_nope, p["w_uk"])  # [B,1,H,R]
-    scale = 1.0 / jnp.sqrt(a.qk_nope_dim + a.qk_rope_dim)
+    scale = mla_softmax_scale(a)
     logits = (jnp.einsum("bshr,btr->bhst", q_abs, cache_c) +
               jnp.einsum("bshk,btk->bhst", q_rope, cache_kr)).astype(jnp.float32)
     logits = logits * scale

@@ -1,36 +1,41 @@
-"""Mixture-of-Experts FFN with capacity-based expert-parallel dispatch.
+"""Mixture-of-Experts FFN: token-choice top-k routing over every routed
+expert, and dropless dispatch to the experts this layer holds.
 
-TPU mapping: expert weights are sharded over the ``model`` mesh axis;
-activations are replicated across ``model`` (tensor-parallel layout), so
-"dispatch" is a local gather of each shard's experts' tokens — the only
-collective is the output reduction, which XLA emits as an all-reduce
-over ``model``.  This adapts the paper-agnostic GShard capacity design
-to the mesh used by this framework (see DESIGN.md §2/§6).
+Expert parallelism: a layer shared by several chips gives each of them a
+range of its experts, ``[expert_first, expert_first + n_held)`` of
+``n_experts``.  The router keeps its full width and its top-k; the layer
+computes what its own experts contribute for the (token, expert) pairs
+routed to them, and the shared experts once.  Pairs routed to experts
+held elsewhere contribute nothing here.  With ``n_held`` 0 the layer
+holds every expert.
 
-Routing is token-choice top-k with per-expert capacity
-``C_e = ceil(T * k / E * capacity_factor)``; over-capacity assignments
-are dropped (standard GShard semantics).  Setting
-``capacity_factor >= E / k`` makes dispatch lossless — tests use that to
-compare against the dense oracle in ``ref_dense_moe``.
+Dispatch drops no tokens.  The T·k pairs are sorted by expert, so the
+pairs of each held expert form one contiguous group (those of experts
+held elsewhere go last), and three grouped matrix products
+(``jax.lax.ragged_dot``) run over the groups.  On a TPU the grouped
+product is a kernel whose work follows the group sizes, not the buffer.
+The buffer holds all T·k pairs, the worst case, so shapes stay static
+however unbalanced the routing.  Each pair's weighted output is then
+added back to its token (a gather and a sum over the token's k pairs).
 """
 from __future__ import annotations
 
-import math
+import functools
 from typing import Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import PartitionSpec as P
+from jax.custom_batching import sequential_vmap
 
-from ..configs.base import ModelConfig, MoESpec
+from ..configs.base import MoESpec
 from .layers import activate
-from .params import ParamDef, shard_hint
+from .params import ParamDef
 
 
 def moe_defs(d_model: int, m: MoESpec) -> dict:
-    e, f = m.n_experts, m.d_ff_expert
+    e, f = m.held, m.d_ff_expert
     d = {
-        "router": ParamDef((d_model, e), ("embed", None)),
+        "router": ParamDef((d_model, m.n_experts), ("embed", None)),
         "w_in": ParamDef((e, d_model, f), ("experts", "embed", "ff")),
         "w_gate": ParamDef((e, d_model, f), ("experts", "embed", "ff")),
         "w_out": ParamDef((e, f, d_model), ("experts", "ff", "embed")),
@@ -42,109 +47,187 @@ def moe_defs(d_model: int, m: MoESpec) -> dict:
     return d
 
 
-def capacity(n_tokens: int, m: MoESpec) -> int:
-    c = math.ceil(n_tokens * m.top_k * m.capacity_factor / m.n_experts)
-    return max(4, int(c))
-
-
-def route(router_w, x, m: MoESpec):
-    """Returns (weights [T,k], expert ids [T,k], aux losses)."""
+def route(router_w, x, m: MoESpec, seq_len: int):
+    """x [T, d]: B = T / seq_len sequences of seq_len tokens, in order.
+    Returns (weights [T,k], expert ids [T,k], balance loss)."""
     logits = (x.astype(jnp.float32) @ router_w.astype(jnp.float32))
     probs = jax.nn.softmax(logits, axis=-1)                  # [T,E]
     w, ids = jax.lax.top_k(probs, m.top_k)                   # [T,k]
-    w = w / jnp.maximum(jnp.sum(w, axis=-1, keepdims=True), 1e-9)
-    # aux: load-balance (Switch) + router z-loss
-    T = x.shape[0]
+    if m.norm_topk_prob:
+        w = w / jnp.maximum(jnp.sum(w, axis=-1, keepdims=True), 1e-9)
+    w = w * m.routed_scaling_factor
+    T, E, k = x.shape[0], m.n_experts, m.top_k
+    if m.balance == "seq":
+        # DeepSeek-V2: per sequence, each expert's share of the pairs
+        # (scaled so that an even spread reads 1) times its mean
+        # probability, summed over experts, averaged over sequences
+        B = T // seq_len
+        ce = jnp.zeros((B, E)).at[jnp.arange(B)[:, None],
+                                  ids.reshape(B, seq_len * k)].add(1.0)
+        ce = ce / (seq_len * k / E)
+        me = jnp.mean(probs.reshape(B, seq_len, E), axis=1)
+        return w, ids, jnp.mean(jnp.sum(ce * me, axis=-1)) * m.aux_loss
+    if m.balance != "switch":
+        raise ValueError(f"unknown balance loss {m.balance!r}")
+    # Switch load balance + router z-loss
     me = jnp.mean(probs, axis=0)                             # mean prob per expert
-    ce = jnp.zeros((m.n_experts,)).at[ids.reshape(-1)].add(1.0) / (T * m.top_k)
-    aux = m.n_experts * jnp.sum(me * ce) * m.aux_loss
+    ce = jnp.zeros((E,)).at[ids.reshape(-1)].add(1.0) / (T * k)
+    aux = E * jnp.sum(me * ce) * m.aux_loss
     z = jnp.mean(jax.scipy.special.logsumexp(logits, axis=-1) ** 2) * m.router_zloss
     return w, ids, aux + z
 
 
-def dispatch_indices(ids, w, m: MoESpec, cap: int):
-    """GShard-style position-in-expert computation.
-
-    ids/w: [T, k].  Returns token index matrix [E, C], combine weights
-    [E, C], validity [E, C], and the inverse map slot_of [T, k] into the
-    flattened [E*C] slot space (dropped assignments point at slot E*C —
-    a zero pad row on the combine side).
-    """
-    T, k = ids.shape
-    E = m.n_experts
-    flat_ids = ids.reshape(-1)                               # [T*k]
-    flat_w = w.reshape(-1)
-    # position of each assignment within its expert (arrival order)
-    onehot = jax.nn.one_hot(flat_ids, E, dtype=jnp.int32)    # [T*k, E]
-    pos_in_e = jnp.cumsum(onehot, axis=0) * onehot           # 1-based
-    pos = jnp.sum(pos_in_e, axis=-1) - 1                     # [T*k]
-    keep = pos < cap
-    # scatter into [E, C]
-    tok_of = jnp.tile(jnp.arange(T)[:, None], (1, k)).reshape(-1)
-    e_idx = jnp.where(keep, flat_ids, E)                     # drop -> row E
-    p_idx = jnp.where(keep, pos, 0)
-    tok_mat = jnp.zeros((E + 1, cap), jnp.int32).at[e_idx, p_idx].set(tok_of, mode="drop")
-    w_mat = jnp.zeros((E + 1, cap), flat_w.dtype).at[e_idx, p_idx].set(flat_w, mode="drop")
-    val = jnp.zeros((E + 1, cap), bool).at[e_idx, p_idx].set(keep, mode="drop")
-    slot_of = jnp.where(keep, flat_ids * cap + pos, E * cap).reshape(T, k)
-    return tok_mat[:E], w_mat[:E], val[:E], slot_of
+def dispatch(ids, w, m: MoESpec):
+    """The T·k pairs (pair t·k + j: token t's j-th choice) sorted by held
+    expert, those held elsewhere last.  Returns ``order`` (sorted position
+    -> pair), its inverse ``inv`` (pair -> sorted position), each sorted
+    pair's weight (0 where its expert is held elsewhere), and the number
+    of pairs of every held expert ([n_held] int32, the groups of the
+    grouped products)."""
+    local = ids.reshape(-1) - m.expert_first
+    held = (local >= 0) & (local < m.held)
+    group = jnp.where(held, local, m.held)
+    # a counting sort, stable: a pair's place is its group's offset plus
+    # the pairs of its group before it
+    onehot = jax.nn.one_hot(group, m.held + 1, dtype=jnp.int32)
+    before = jnp.sum((jnp.cumsum(onehot, axis=0) - 1) * onehot, axis=-1)
+    counts = jnp.sum(onehot, axis=0)
+    inv = (jnp.cumsum(counts) - counts)[group] + before
+    order = jnp.zeros_like(inv).at[inv].set(jnp.arange(inv.shape[0], dtype=inv.dtype))
+    weight = jnp.where(held, w.reshape(-1), 0.0)[order]
+    return order, inv, weight, counts[:m.held]
 
 
-def moe_ffn(p, x, m: MoESpec, activation: str = "silu",
-            expert_spec: Tuple = ("model",)) -> Tuple[jax.Array, jax.Array]:
-    """x: [T, d] (already flattened tokens).  Returns (out [T,d], aux)."""
-    T, d = x.shape
-    w, ids, aux = route(p["router"], x, m)
-    cap = capacity(T, m)
-    tok, cw, val, slot_of = dispatch_indices(ids, w.astype(x.dtype), m, cap)
-    # shard dispatch tensors over experts so the gather/matmul are local
-    espec = P(expert_spec[0] if len(expert_spec) == 1 else expert_spec)
-    tok = shard_hint(tok, espec)
-    # §Perf dispatch layout: token gathers with data-dependent indices
-    # cannot cross shards without SPMD falling back to masked-gather +
-    # all-reduce of the FULL result.  Reshard x to d-sharded (token dim
-    # whole) so the gather is local, then a2a the packed [E,C,d] to the
-    # expert layout.
-    xd = shard_hint(x, P(None, espec[0]))
-    xe = xd[tok]                                             # [E, C, d]
-    xe = jnp.where(val[..., None], xe, 0)
-    xe = shard_hint(xe, P(espec[0], None, None))
-    h = jnp.einsum("ecd,edf->ecf", xe, p["w_in"])
-    g = jnp.einsum("ecd,edf->ecf", xe, p["w_gate"])
-    h = activate(g, activation) * h
-    ye = jnp.einsum("ecf,efd->ecd", h, p["w_out"])           # [E, C, d]
-    ye = ye * jnp.where(val, cw, 0)[..., None].astype(ye.dtype)
-    # combine: each token GATHERS its k slots from the (padded) expert
-    # outputs.  §Perf: the natural scatter-add combine is unshardable
-    # under SPMD (XLA all-gathers the 8GB token tensor per layer).  The
-    # gather side is made LOCAL by resharding the [E*C+1, d] expert
-    # outputs to d-sharded (slot dim whole) — one a2a — after which the
-    # backward is a local scatter-add on that small tensor.
-    ye_flat = jnp.concatenate(
-        [ye.reshape(ye.shape[0] * cap, d),
-         jnp.zeros((1, d), ye.dtype)], axis=0)               # slot E*C = 0
-    ye_flat = shard_hint(ye_flat, P(None, espec[0]))
-    out = jnp.sum(ye_flat[slot_of], axis=1)                  # [T,k,d/s]->[T,d/s]
-    out = shard_hint(out, P(espec[0], None))                 # back to seq-shard
+# Rows move between tokens and sorted pairs by gathers alone: the two
+# maps below are each other's transpose (the pairs are a permutation of
+# k copies of the tokens), so neither pass scatters.
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def to_pairs(x, order, inv, k: int):
+    """[T, d] token rows -> [T·k, d] rows of the sorted pairs."""
+    return x[order // k]
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def to_tokens(y, order, inv, k: int):
+    """[T·k, d] rows of the sorted pairs -> [T, d], each token's k rows
+    summed."""
+    return jnp.sum(y[inv].reshape(-1, k, y.shape[-1]), axis=1)
+
+
+to_pairs.defvjp(lambda x, order, inv, k: (to_pairs(x, order, inv, k), (order, inv)),
+                lambda k, res, dy: (to_tokens(dy, *res, k), None, None))
+to_tokens.defvjp(lambda y, order, inv, k: (to_tokens(y, order, inv, k), (order, inv)),
+                 lambda k, res, dy: (to_pairs(dy, *res, k), None, None))
+
+
+def _grouped(x, w, sizes):
+    """Rows of x grouped by expert times their expert's matrix.  Rows
+    past the last group are left unspecified (a TPU leaves them
+    unwritten)."""
+    return jax.lax.ragged_dot(x, w, sizes)
+
+
+def _weight_grad(x, dy, w, sizes):
+    """The gradient of ``_grouped(x, w, sizes)`` in w: each group's
+    x^T dy, over its own rows alone."""
+    _, vjp = jax.vjp(lambda w: jax.lax.ragged_dot(x, w, sizes), w)
+    return vjp(dy)[0]
+
+
+def _in_groups(x, sizes):
+    """x with the rows past the last group zeroed (a select: those rows
+    may hold anything)."""
+    rows = jnp.arange(x.shape[0]) < jnp.sum(sizes)
+    return jnp.where(rows[:, None], x, jnp.zeros((), x.dtype))
+
+
+def _grouped_mlp(xs, w_in, w_gate, w_out, sizes, activation):
+    h = _grouped(xs, w_in, sizes)
+    g = _grouped(xs, w_gate, sizes)
+    y = _grouped(activate(g, activation) * h, w_out, sizes)
+    return _in_groups(y, sizes), (h, g)
+
+
+# ``ragged_dot`` has no batching rule for unbatched weights, and the
+# training step maps the loss over its workers: each worker's grouped
+# products run in turn under that map (``sequential_vmap``), forward and
+# backward alike.  The backward is written out so that the rows past the
+# last group, which a TPU leaves unwritten, never reach a token.
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
+def expert_mlp(xs, w_in, w_gate, w_out, sizes, activation: str):
+    """The held experts' gated MLPs over rows grouped by expert:
+    ``sizes[e]`` consecutive rows go to held expert e, and the rows past
+    their sum come out as zeros."""
+    return _expert_fwd(xs, w_in, w_gate, w_out, sizes, activation)[0]
+
+
+def _expert_fwd(xs, w_in, w_gate, w_out, sizes, activation):
+    y, hg = sequential_vmap(functools.partial(_grouped_mlp, activation=activation))(
+        xs, w_in, w_gate, w_out, sizes)
+    return y, (xs, w_in, w_gate, w_out, sizes, *hg)
+
+
+def _expert_bwd_one(xs, w_in, w_gate, w_out, sizes, h, g, dy, activation):
+    a, act_vjp = jax.vjp(lambda h, g: activate(g, activation) * h, h, g)
+    dw_out = _weight_grad(a, dy, w_out, sizes)
+    dh, dg = act_vjp(_grouped(dy, jnp.swapaxes(w_out, 1, 2), sizes))
+    dx = (_grouped(dh, jnp.swapaxes(w_in, 1, 2), sizes)
+          + _grouped(dg, jnp.swapaxes(w_gate, 1, 2), sizes))
+    return (_in_groups(dx, sizes), _weight_grad(xs, dh, w_in, sizes),
+            _weight_grad(xs, dg, w_gate, sizes), dw_out)
+
+
+def _expert_bwd(activation, res, dy):
+    grads = sequential_vmap(functools.partial(_expert_bwd_one, activation=activation))(
+        *res, dy)
+    return (*grads, None)
+
+
+expert_mlp.defvjp(_expert_fwd, _expert_bwd)
+
+
+def shared_mlp(p, x, activation: str):
+    hs = x @ p["shared_in"]
+    hs = activate(x @ p["shared_gate"], activation) * hs
+    return hs @ p["shared_out"]
+
+
+def moe_ffn(p, x, m: MoESpec, activation: str = "silu"
+            ) -> Tuple[jax.Array, jax.Array, dict]:
+    """x: [B, S, d].  Returns (out [B,S,d], balance loss, counters):
+    ``pairs``, the pairs that held experts computed, and ``load_max``,
+    the largest held expert's pairs over the held experts' mean."""
+    B, S, d = x.shape
+    xf = x.reshape(B * S, d)
+    with jax.named_scope("router"):
+        w, ids, aux = route(p["router"], xf, m, S)
+    with jax.named_scope("dispatch"):
+        order, inv, weight, sizes = dispatch(ids, w, m)
+        xs = to_pairs(xf, order, inv, m.top_k)               # [T*k, d]
+    with jax.named_scope("experts"):
+        ys = expert_mlp(xs, p["w_in"], p["w_gate"], p["w_out"], sizes, activation)
+    with jax.named_scope("dispatch"):
+        out = to_tokens(ys * weight[:, None].astype(ys.dtype), order, inv, m.top_k)
     if m.n_shared:
-        hs = x @ p["shared_in"]
-        hs = activate(x @ p["shared_gate"], activation) * hs
-        out = out + hs @ p["shared_out"]
-    return out, aux
+        with jax.named_scope("shared_experts"):
+            out = out + shared_mlp(p, xf, activation)
+    pairs = jnp.sum(sizes).astype(jnp.float32)
+    load_max = jnp.max(sizes).astype(jnp.float32) / jnp.maximum(pairs / m.held, 1e-9)
+    return out.reshape(B, S, d), aux, {"pairs": pairs, "load_max": load_max}
 
 
 def ref_dense_moe(p, x, m: MoESpec, activation: str = "silu"):
-    """Oracle: computes every expert on every token, combines with router
-    weights.  O(T·E·d·f) — tests only."""
-    w, ids, _ = route(p["router"], x, m)
-    h = jnp.einsum("td,edf->tef", x, p["w_in"])
-    g = jnp.einsum("td,edf->tef", x, p["w_gate"])
+    """Oracle: every held expert on every token, combined with the router
+    weights of the pairs routed to it.  O(T·E·d·f) — tests only."""
+    B, S, d = x.shape
+    xf = x.reshape(B * S, d)
+    w, ids, _ = route(p["router"], xf, m, S)
+    h = jnp.einsum("td,edf->tef", xf, p["w_in"])
+    g = jnp.einsum("td,edf->tef", xf, p["w_gate"])
     y = jnp.einsum("tef,efd->ted", activate(g, activation) * h, p["w_out"])
-    combine = jnp.zeros((x.shape[0], m.n_experts), y.dtype)
-    combine = combine.at[jnp.arange(x.shape[0])[:, None], ids].add(w.astype(y.dtype))
+    onehot = jax.nn.one_hot(ids - m.expert_first, m.held, dtype=y.dtype)
+    combine = jnp.einsum("tk,tke->te", w.astype(y.dtype), onehot)
     out = jnp.einsum("te,ted->td", combine, y)
     if m.n_shared:
-        hs = x @ p["shared_in"]
-        hs = activate(x @ p["shared_gate"], activation) * hs
-        out = out + hs @ p["shared_out"]
-    return out
+        out = out + shared_mlp(p, xf, activation)
+    return out.reshape(B, S, d)

@@ -104,7 +104,9 @@ def param_defs(cfg: ModelConfig) -> dict:
 
 def _attention(cfg, p, x, positions):
     if cfg.attention.kind == "mla":
-        out, kv = L.mla_attention(p, cfg.attention, x, positions)
+        with jax.named_scope("mla"):
+            out, kv = L.mla_attention(p, cfg.attention, x, positions,
+                                      eps=cfg.rms_eps)
     else:
         out, kv = L.gqa_attention(p, cfg.attention, x, positions)
     return out, kv
@@ -117,20 +119,43 @@ def _kv_entry(cfg, kv):
     return {"k": kv[0], "v": kv[1]}
 
 
+def _zero_stats(kind: str) -> dict:
+    """A block's scalars that the scan carries: the balance loss, and in
+    an MoE segment the routing counters."""
+    z = jnp.zeros((), jnp.float32)
+    if kind == "moe":
+        return {"aux": z, "moe_pairs": z, "moe_load_max": z}
+    return {"aux": z}
+
+
+def _add_stats(acc: dict, new: dict) -> dict:
+    """Sums over layers, but the largest load is a maximum."""
+    out = dict(acc)
+    for k, v in new.items():
+        if k not in out:
+            out[k] = v
+        elif k == "moe_load_max":
+            out[k] = jnp.maximum(out[k], v)
+        else:
+            out[k] = out[k] + v
+    return out
+
+
 def _dense_block(cfg, p, x, positions):
     h, kv = _attention(cfg, p["attn"], L.rms_norm(x, p["ln1"], cfg.rms_eps), positions)
     x = x + h
     x = x + L.mlp(p["mlp"], L.rms_norm(x, p["ln2"], cfg.rms_eps), cfg.activation)
-    return x, jnp.zeros((), jnp.float32), _kv_entry(cfg, kv)
+    return x, _zero_stats("dense"), _kv_entry(cfg, kv)
 
 
 def _moe_block(cfg, p, x, positions):
     h, kv = _attention(cfg, p["attn"], L.rms_norm(x, p["ln1"], cfg.rms_eps), positions)
     x = x + h
-    B, S, D = x.shape
-    flat = L.rms_norm(x, p["ln2"], cfg.rms_eps).reshape(B * S, D)
-    out, aux = MOE.moe_ffn(p["moe"], flat, cfg.moe, cfg.activation)
-    return x + out.reshape(B, S, D), aux, _kv_entry(cfg, kv)
+    with jax.named_scope("moe"):
+        out, aux, cnt = MOE.moe_ffn(p["moe"], L.rms_norm(x, p["ln2"], cfg.rms_eps),
+                                    cfg.moe, cfg.activation)
+    stats = {"aux": aux, "moe_pairs": cnt["pairs"], "moe_load_max": cnt["load_max"]}
+    return x + out, stats, _kv_entry(cfg, kv)
 
 
 def _rwkv_block(cfg, p, x, positions):
@@ -138,7 +163,7 @@ def _rwkv_block(cfg, p, x, positions):
                                       L.rms_norm(x, p["ln1"], cfg.rms_eps))
     x = x + h
     h, cm_x = R6.rwkv6_channelmix(p["tm"], L.rms_norm(x, p["ln2"], cfg.rms_eps))
-    return (x + h, jnp.zeros((), jnp.float32),
+    return (x + h, _zero_stats("rwkv"),
             {"wkv": wkv, "tm_x": tm_x, "cm_x": cm_x})
 
 
@@ -153,7 +178,8 @@ _SP_SPEC = P(None, "model", None)  # sequence-parallel activation layout
 
 def _run_segment(cfg, seg: Segment, p_stack, shared, x, positions, remat=False,
                  param_hook=None, collect_cache=False):
-    """Scan a stacked segment over x.  Returns (x, aux_sum, cache_ys).
+    """Scan a stacked segment over x.  Returns (x, stats, cache_ys), stats
+    being the blocks' scalars over the segment (``_add_stats``).
 
     ``param_hook(p_layer, layer_idx)`` is applied to each scanned
     layer-slice of the parameter stack — identity by default.  The
@@ -173,7 +199,7 @@ def _run_segment(cfg, seg: Segment, p_stack, shared, x, positions, remat=False,
 
     def body(carry, idx_p):
         idx, p_l = idx_p
-        x, aux = carry
+        x, stats = carry
         if param_hook is not None:
             p_l = param_hook(p_l, idx)
         x = shard_hint(x, _SP_SPEC)
@@ -192,13 +218,13 @@ def _run_segment(cfg, seg: Segment, p_stack, shared, x, positions, remat=False,
             ent = {"mamba": m_ent, "attn": a_ent}
         else:
             raise ValueError(seg.kind)
-        return (x, aux + a), (ent if collect_cache else None)
+        return (x, _add_stats(stats, a)), (ent if collect_cache else None)
 
     if remat:
         body = jax.checkpoint(body, prevent_cse=False)
-    (x, aux), ys = jax.lax.scan(body, (x, jnp.zeros((), jnp.float32)),
-                                (jnp.arange(seg.n, dtype=jnp.float32), p_stack))
-    return x, aux, ys
+    (x, stats), ys = jax.lax.scan(body, (x, _zero_stats(seg.kind)),
+                                  (jnp.arange(seg.n, dtype=jnp.float32), p_stack))
+    return x, stats, ys
 
 
 # ---------------------------------------------------------------------------
@@ -220,6 +246,13 @@ def forward(cfg: ModelConfig, params, tokens, prefix_embed=None, remat=False,
     scanned layer slice of segment i; ``top_hook`` once to the
     non-stacked bucket (embed / final_norm / lm_head / shared_attn).
     """
+    logits, stats = _forward(cfg, params, tokens, prefix_embed, remat,
+                             seg_hooks, top_hook)
+    return logits, stats["aux"]
+
+
+def _forward(cfg, params, tokens, prefix_embed, remat, seg_hooks, top_hook):
+    """:func:`forward` with every block scalar: (logits, stats)."""
     if top_hook is not None:
         top = {k: v for k, v in params.items() if not k.startswith("seg_")}
         top = top_hook(top)
@@ -227,28 +260,31 @@ def forward(cfg: ModelConfig, params, tokens, prefix_embed=None, remat=False,
     x = embed_inputs(cfg, params, tokens, prefix_embed)
     S = x.shape[1]
     positions = jnp.arange(S)
-    aux = jnp.zeros((), jnp.float32)
+    stats = _zero_stats("dense")
     for i, seg in enumerate(segments(cfg)):
         hook = (seg_hooks or {}).get(f"seg_{i}")
-        x, a, _ = _run_segment(cfg, seg, params[f"seg_{i}"],
-                               params.get("shared_attn"), x, positions, remat,
-                               hook)
-        aux = aux + a
+        x, st, _ = _run_segment(cfg, seg, params[f"seg_{i}"],
+                                params.get("shared_attn"), x, positions, remat,
+                                hook)
+        stats = _add_stats(stats, st)
     x = L.rms_norm(x, params["final_norm"], cfg.rms_eps)
     head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
     with jax.named_scope("lm_head"):
         logits = x @ head
         logits = shard_hint(logits, P(None, None, "model"))
-    return logits, aux
+    return logits, stats
 
 
 def loss_fn(cfg: ModelConfig, params, batch, remat=False, seg_hooks=None,
             top_hook=None):
     """Next-token cross-entropy over the token positions (prefix embeds
-    from modality frontends are context only)."""
+    from modality frontends are context only), plus the balance loss.
+    The metrics hold ``ce``, ``aux`` and, in an MoE model, the routing
+    counters ``moe_pairs`` and ``moe_load_max``."""
     tokens = batch["tokens"]
-    logits, aux = forward(cfg, params, tokens, batch.get("prefix_embed"), remat,
-                          seg_hooks, top_hook)
+    logits, stats = _forward(cfg, params, tokens, batch.get("prefix_embed"),
+                             remat, seg_hooks, top_hook)
+    aux = stats["aux"]
     Pfx = logits.shape[1] - tokens.shape[1]
     # logits at position Pfx+t predict tokens[t+1]
     with jax.named_scope("lm_head"):
@@ -262,7 +298,7 @@ def loss_fn(cfg: ModelConfig, params, batch, remat=False, seg_hooks=None,
             ce = -jnp.sum(ll * mask) / jnp.maximum(jnp.sum(mask), 1.0)
         else:
             ce = -jnp.mean(ll)
-    return ce + aux, {"ce": ce, "aux": aux}
+    return ce + aux, {"ce": ce, **stats}
 
 
 # ---------------------------------------------------------------------------
@@ -322,7 +358,9 @@ def init_cache(cfg: ModelConfig, batch: int, seq_len: int, dtype=jnp.bfloat16):
 def _attn_decode(cfg, p, x, cache, pos):
     a = cfg.attention
     if a.kind == "mla":
-        out, (c, kr) = L.mla_decode(p, a, x, cache["c"], cache["kr"], pos)
+        with jax.named_scope("mla"):
+            out, (c, kr) = L.mla_decode(p, a, x, cache["c"], cache["kr"], pos,
+                                        eps=cfg.rms_eps)
         return out, {"c": c, "kr": kr}
     out, (k, v) = L.gqa_decode(p, a, x, cache["k"], cache["v"], pos)
     return out, {"k": k, "v": v}
@@ -350,10 +388,10 @@ def decode_step(cfg: ModelConfig, params, cache, token, pos):
                 x = x + h
                 hin = L.rms_norm(x, p_l["ln2"], cfg.rms_eps)
                 if seg.kind == "moe":
-                    B = x.shape[0]
-                    out, _ = MOE.moe_ffn(p_l["moe"], hin.reshape(B, -1), cfg.moe,
-                                         cfg.activation)
-                    x = x + out.reshape(B, 1, -1)
+                    with jax.named_scope("moe"):
+                        out, _, _ = MOE.moe_ffn(p_l["moe"], hin, cfg.moe,
+                                                cfg.activation)
+                    x = x + out
                 else:
                     x = x + L.mlp(p_l["mlp"], hin, cfg.activation)
                 return x, c_new

@@ -18,9 +18,8 @@ Recurrent (rwkv/mamba) or windowed configs prefill at EXACT length —
 padding would corrupt the carried O(1) state / ring buffer — costing one
 compile per distinct prompt length (DESIGN.md §Serve).
 
-MoE caveat: routing is cross-batch, so dead slots consume expert
-capacity in batched decode; at serve batch sizes this only perturbs
-capacity-dropped tokens (exact parity tests use dense configs).
+MoE: dispatch drops no tokens, so dead slots in batched decode add
+routed pairs but change no live slot's output.
 
 Stalls + timeouts (DESIGN.md §Faults): a slot can stop making progress
 (wedged device — injected by the ``slot_stall`` fault via

@@ -53,6 +53,11 @@ from ..models import transformer as TF
 from ..optim import get_optimizer
 
 GIANT_PARAMS = 20e9
+# an MoE model's routing counters in the step's metrics: the (token,
+# expert) pairs its held experts computed, summed over layers and
+# workers, and the fullest held expert's load over the held mean, the
+# largest over layers and workers
+MOE_COUNTERS = ("moe_pairs", "moe_load_max")
 
 
 def resolve_strategy(tcfg: TrainConfig) -> tuple[str, str]:
@@ -150,6 +155,8 @@ def _build_blocked_step(tcfg, mesh, opt, layout):
               "n_selected_min": metric_spec}
     if guard:
         mspecs["worker_ok"] = metric_spec
+    if cfg.is_moe:
+        mspecs.update(dict.fromkeys(MOE_COUNTERS, metric_spec))
 
     @partial(shard_map, mesh=mesh,
              in_specs=(pspecs, ospecs, bspecs, P(), P(), *extra),
@@ -262,6 +269,9 @@ def _build_blocked_step(tcfg, mesh, opt, layout):
         }
         if guard:
             metrics["worker_ok"] = worker_ok
+        if cfg.is_moe:
+            metrics["moe_pairs"] = jax.lax.psum(met["moe_pairs"], waxes)
+            metrics["moe_load_max"] = jax.lax.pmax(met["moe_load_max"], waxes)
         return new_params, new_opt, metrics
 
     return step, pspecs, ospecs, bspecs
@@ -379,6 +389,9 @@ def _build_global_step(tcfg, mesh, opt, layout):
                    "n_selected": n_sel, "n_selected_min": n_sel}
         if guard:
             metrics["worker_ok"] = worker_ok
+        if cfg.is_moe:
+            metrics["moe_pairs"] = jnp.sum(met["moe_pairs"])
+            metrics["moe_load_max"] = jnp.max(met["moe_load_max"])
         return new_params, new_opt, metrics
 
     return step, pspecs, ospecs, bspecs

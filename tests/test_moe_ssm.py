@@ -1,4 +1,6 @@
 """MoE dispatch and SSM/RWKV recurrence correctness."""
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -15,63 +17,178 @@ from repro.models.params import init_params
 # MoE
 # ---------------------------------------------------------------------------
 
-def _moe_setup(E=4, k=2, d=32, f=64, T=48, cap=100.0):
-    spec = MoESpec(n_experts=E, top_k=k, d_ff_expert=f, capacity_factor=cap)
+def _moe_setup(E=4, k=2, d=32, f=64, T=48, **kw):
+    spec = MoESpec(n_experts=E, top_k=k, d_ff_expert=f, **kw)
     p = init_params(MOE.moe_defs(d, spec), jax.random.PRNGKey(0))
-    x = 0.5 * jax.random.normal(jax.random.PRNGKey(1), (T, d), jnp.float32)
+    x = 0.5 * jax.random.normal(jax.random.PRNGKey(1), (2, T // 2, d), jnp.float32)
     return spec, p, x
 
 
 def test_moe_lossless_capacity_matches_dense_oracle():
-    spec, p, x = _moe_setup(cap=100.0)  # capacity >> E/k: nothing dropped
-    out, aux = MOE.moe_ffn(p, x, spec)
+    spec, p, x = _moe_setup()
+    out, aux, cnt = MOE.moe_ffn(p, x, spec)
     ref = MOE.ref_dense_moe(p, x, spec)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=2e-3, atol=2e-3)
     assert bool(jnp.isfinite(aux))
-
-
-def test_moe_capacity_drops_tokens():
-    spec, p, x = _moe_setup(cap=0.25)   # tight capacity: some drops
-    out, _ = MOE.moe_ffn(p, x, spec)
-    ref = MOE.ref_dense_moe(p, x, spec)
-    # dropped tokens make out != ref, but out stays finite and bounded
-    assert bool(jnp.isfinite(out).all())
-    assert float(jnp.abs(out).max()) < 1e3
+    assert float(cnt["pairs"]) == x.shape[0] * x.shape[1] * spec.top_k
 
 
 def test_moe_router_normalized_topk():
     spec, p, x = _moe_setup()
-    w, ids, aux = MOE.route(p["router"], x, spec)
-    assert w.shape == (x.shape[0], spec.top_k)
+    w, ids, aux = MOE.route(p["router"], x.reshape(-1, x.shape[-1]), spec,
+                            x.shape[1])
+    assert w.shape == (x.shape[0] * x.shape[1], spec.top_k)
     np.testing.assert_allclose(np.asarray(w.sum(-1)), 1.0, rtol=1e-4)
     assert int(ids.max()) < spec.n_experts
 
 
 def test_moe_shared_experts_added():
-    spec = MoESpec(n_experts=4, top_k=1, d_ff_expert=16, n_shared=2,
-                   capacity_factor=100.0)
+    spec = MoESpec(n_experts=4, top_k=1, d_ff_expert=16, n_shared=2)
     p = init_params(MOE.moe_defs(8, spec), jax.random.PRNGKey(0))
-    x = jax.random.normal(jax.random.PRNGKey(1), (10, 8), jnp.float32)
-    out, _ = MOE.moe_ffn(p, x, spec)
+    x = jax.random.normal(jax.random.PRNGKey(1), (2, 5, 8), jnp.float32)
+    out, _, _ = MOE.moe_ffn(p, x, spec)
     ref = MOE.ref_dense_moe(p, x, spec)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=2e-3, atol=2e-3)
 
 
-def test_dispatch_indices_positions_within_capacity():
-    spec = MoESpec(n_experts=3, top_k=1)
-    ids = jnp.asarray([[0], [0], [0], [1], [2], [0]])
-    w = jnp.ones((6, 1), jnp.float32)
-    tok, cw, val, slot_of = MOE.dispatch_indices(ids, w, spec, cap=2)
-    # expert 0 receives tokens 0,1 (2 = cap); tokens 2 and 5 dropped
-    assert np.asarray(val)[0].sum() == 2
-    assert set(np.asarray(tok)[0][np.asarray(val)[0]]) == {0, 1}
-    # inverse map: dropped assignments point at the zero pad slot E*C
-    so = np.asarray(slot_of).reshape(-1)
-    assert so[2] == 3 * 2 and so[5] == 3 * 2          # dropped -> pad
-    assert so[0] == 0 and so[1] == 1                  # expert0 slots 0,1
-    assert so[3] == 1 * 2 + 0 and so[4] == 2 * 2 + 0  # experts 1,2 pos 0
+def _share(p, first, n):
+    """The parameters a layer holding experts [first, first + n) has."""
+    return {k: (v[first:first + n] if k in ("w_in", "w_gate", "w_out") else v)
+            for k, v in p.items()}
+
+
+def test_moe_expert_shares_add_up_to_the_whole_layer():
+    """Two layers holding experts 0-1 and 2-3 of 4: their outputs add up
+    to the uncut layer's, with the shared experts counted once."""
+    spec, p, x = _moe_setup(n_shared=1)
+    whole, _, cnt = MOE.moe_ffn(p, x, spec)
+    shared = MOE.shared_mlp(p, x, "silu")
+    parts, pairs = 0.0, 0.0
+    for first in (0, 2):
+        sub = dataclasses.replace(spec, expert_first=first, n_held=2)
+        out, _, c = MOE.moe_ffn(_share(p, first, 2), x, sub)
+        parts = parts + (out - shared)
+        pairs += float(c["pairs"])
+    np.testing.assert_allclose(np.asarray(parts + shared), np.asarray(whole),
+                               rtol=1e-5, atol=1e-5)
+    assert pairs == float(cnt["pairs"]) == x.shape[0] * x.shape[1] * spec.top_k
+
+
+def test_moe_share_matches_the_oracle_of_its_experts():
+    spec, p, x = _moe_setup(E=8, k=3, n_shared=1)
+    sub = dataclasses.replace(spec, expert_first=4, n_held=2)
+    out, _, _ = MOE.moe_ffn(_share(p, 4, 2), x, sub)
+    ref = MOE.ref_dense_moe(_share(p, 4, 2), x, sub)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                               rtol=1e-5, atol=1e-5)
+
+
+def test_moe_imbalanced_routing_drops_nothing():
+    """Every token routed to the same two experts: all of its pairs are
+    computed, and the result is the dense oracle's."""
+    spec, p, x = _moe_setup(E=4, k=2, norm_topk_prob=False)
+    # one feature that every token holds, which the router reads as a
+    # strong vote for experts 0 and 1
+    x = x.at[..., 0].set(2.0)
+    p = dict(p, router=p["router"].at[0, :2].set(20.0).at[0, 2:].set(-20.0))
+    w, ids, _ = MOE.route(p["router"], x.reshape(-1, x.shape[-1]), spec, x.shape[1])
+    assert set(np.unique(np.asarray(ids))) == {0, 1}
+    out, _, cnt = MOE.moe_ffn(p, x, spec)
+    T = x.shape[0] * x.shape[1]
+    assert float(cnt["pairs"]) == T * spec.top_k
+    assert float(cnt["load_max"]) == pytest.approx(2.0)   # 2 of 4 experts busy
+    ref = MOE.ref_dense_moe(p, x, spec)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                               rtol=1e-5, atol=1e-5)
+
+
+def test_moe_dispatch_groups_pairs_by_held_expert():
+    spec = MoESpec(n_experts=4, top_k=2, expert_first=1, n_held=2)
+    ids = jnp.asarray([[1, 3], [2, 1], [0, 2]])
+    w = jnp.full((3, 2), 0.5, jnp.float32)
+    order, inv, weight, sizes = MOE.dispatch(ids, w, spec)
+    assert np.asarray(sizes).tolist() == [2, 2]           # experts 1 and 2
+    # pairs (token, choice): expert 1 holds (0,0), (1,1); expert 2 (1,0), (2,1)
+    assert np.asarray(order).tolist()[:4] == [0, 3, 2, 5]
+    assert np.asarray(order)[np.asarray(inv)].tolist() == list(range(6))
+    assert np.asarray(weight).tolist() == [0.5] * 4 + [0.0] * 2
+    x = jnp.arange(3.0)[:, None] * jnp.ones((1, 2))
+    rows = MOE.to_pairs(x, order, inv, 2)
+    assert np.asarray(rows[:, 0]).tolist()[:4] == [0.0, 1.0, 1.0, 2.0]
+    np.testing.assert_array_equal(MOE.to_tokens(rows, order, inv, 2), 2 * x)
+
+
+@pytest.mark.parametrize("fn", ["to_pairs", "to_tokens"])
+def test_moe_pair_maps_transpose_each_other(fn):
+    """Each map's gradient is the other map, as autodiff of a plain
+    gather would give it."""
+    ids = jnp.asarray([[3, 0], [2, 1], [0, 3]])
+    order = jnp.argsort(ids.reshape(-1), stable=True)
+    inv = jnp.argsort(order)
+    plain = {"to_pairs": lambda x: x[order // 2],
+             "to_tokens": lambda y: jnp.zeros((3, 4)).at[order // 2].add(y)}[fn]
+    shape = (3, 4) if fn == "to_pairs" else (6, 4)
+    x = jax.random.normal(jax.random.PRNGKey(0), shape)
+    mapped = lambda x: getattr(MOE, fn)(x, order, inv, 2)
+    np.testing.assert_allclose(mapped(x), plain(x), rtol=1e-6)
+    ct = jax.random.normal(jax.random.PRNGKey(1), plain(x).shape)
+    np.testing.assert_allclose(jax.vjp(mapped, x)[1](ct)[0],
+                               jax.vjp(plain, x)[1](ct)[0], rtol=1e-6)
+
+
+def test_moe_rows_past_the_groups_never_reach_a_token(monkeypatch):
+    """A TPU leaves the grouped products' rows past the last group
+    unwritten: filled with NaN here, the layer's output and gradients
+    are still the oracle's."""
+    spec, p, x = _moe_setup(E=8, k=2, n_shared=1, expert_first=2, n_held=3)
+
+    def loss(fn, p, x):
+        return jnp.sum(jnp.sin(fn(p, x, spec)[0] if fn is MOE.moe_ffn
+                               else fn(p, x, spec)))
+
+    want = jax.value_and_grad(lambda p, x: loss(MOE.ref_dense_moe, p, x),
+                              argnums=(0, 1))(p, x)
+    grouped = MOE._grouped
+
+    def unwritten(x, w, sizes):
+        out = grouped(x, w, sizes)
+        past = jnp.arange(out.shape[0]) >= jnp.sum(sizes)
+        return jnp.where(past[:, None], jnp.nan, out)
+
+    monkeypatch.setattr(MOE, "_grouped", unwritten)
+    got = jax.value_and_grad(lambda p, x: loss(MOE.moe_ffn, p, x),
+                             argnums=(0, 1))(p, x)
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        assert bool(jnp.all(jnp.isfinite(a)))
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-4, atol=1e-5)
+
+
+def test_moe_router_without_renormalisation_keeps_probabilities():
+    spec, p, x = _moe_setup(norm_topk_prob=False, routed_scaling_factor=2.0)
+    xf = x.reshape(-1, x.shape[-1])
+    w, ids, _ = MOE.route(p["router"], xf, spec, x.shape[1])
+    probs = jax.nn.softmax(xf @ p["router"], axis=-1)
+    want = 2.0 * jnp.take_along_axis(probs, ids, axis=-1)
+    np.testing.assert_allclose(np.asarray(w), np.asarray(want), rtol=1e-6)
+    assert float(jnp.max(w.sum(-1) / 2.0)) < 1.0
+
+
+def test_moe_sequence_balance_loss_by_hand():
+    """DeepSeek-V2's sequence-wise loss: alpha * mean over sequences of
+    sum_e (count_e * E / (S * k)) * mean_s p_e."""
+    spec, p, x = _moe_setup(balance="seq", aux_loss=0.1)
+    xf = x.reshape(-1, x.shape[-1])
+    B, S, E, k = x.shape[0], x.shape[1], spec.n_experts, spec.top_k
+    _, ids, aux = MOE.route(p["router"], xf, spec, S)
+    probs = np.asarray(jax.nn.softmax(xf @ p["router"], axis=-1)).reshape(B, S, E)
+    ids = np.asarray(ids).reshape(B, S * k)
+    want = 0.0
+    for b in range(B):
+        count = np.bincount(ids[b], minlength=E) * E / (S * k)
+        want += np.sum(count * probs[b].mean(0)) / B
+    assert float(aux) == pytest.approx(0.1 * want, rel=1e-5)
 
 
 # ---------------------------------------------------------------------------

@@ -136,7 +136,7 @@ def test_image_pipeline_splits_and_flips():
 
 def test_registry_has_all_assigned():
     assert set(ARCHS) == {
-        "deepseek-v2-236b", "phi-3-vision-4.2b", "nemotron-4-15b",
+        "deepseek-v2-236b", "deepseek-v2-lite", "phi-3-vision-4.2b", "nemotron-4-15b",
         "musicgen-large", "minicpm3-4b", "dbrx-132b", "zamba2-2.7b",
         "qwen3-0.6b", "qwen3-1.7b", "rwkv6-7b"}
     assert set(SHAPES) == {"train_4k", "prefill_32k", "decode_32k", "long_500k"}
