@@ -26,6 +26,7 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 from jax.custom_batching import sequential_vmap
+from jax.experimental.xla_metadata import set_xla_metadata
 
 from ..configs.base import MoESpec
 from .layers import activate
@@ -120,17 +121,54 @@ to_tokens.defvjp(lambda y, order, inv, k: (to_tokens(y, order, inv, k), (order, 
                  lambda k, res, dy: (to_pairs(dy, *res, k), None, None))
 
 
+# The TPU compiler's grouped-product kernel takes its tiles (rows,
+# contracted width, output width) from the ``ragged_dot_tiling``
+# attribute where one is set.  Its own choice takes 128 or 512 output
+# columns a tile, and reads every row of the input once for each tile of
+# columns: at the widths of an expert (2,048 x 1,408) that made the
+# products cost about three times the bytes of a tile as wide as the
+# output, and that cost follows the routed pairs.  The forward products
+# take the whole output width in a tile, the weight gradients tiles of
+# 512 x 512; shapes these tiles do not fit keep the compiler's choice.
+ROW_TILE = 512
+_VMEM_BUDGET = 14 * 2**20       # bytes of tiles; the compiler refused 21 MiB
+
+
+def _tiling(rows: int, k: int, n: int, weight_grad: bool):
+    """The ``ragged_dot_tiling`` of a product over ``rows`` grouped rows
+    that contracts ``k`` and gives ``n`` columns ([groups, k, n] for a
+    weight gradient), or None for the compiler's own."""
+    if rows % ROW_TILE:
+        return None
+    if weight_grad:
+        return f"{ROW_TILE},{min(k, 512)},{min(n, 512)}"
+    for tk in (512, 256, 128):
+        # the row and weight tiles twice (one in flight), the output tile twice
+        vmem = 4 * (2 * (ROW_TILE * tk + tk * n) + 2 * ROW_TILE * n)
+        if k % tk == 0 and vmem <= _VMEM_BUDGET:
+            return f"{ROW_TILE},{tk},{n}"
+    return None
+
+
+def _ragged_dot(x, w, sizes, tiling):
+    if tiling is None:
+        return jax.lax.ragged_dot(x, w, sizes)
+    with set_xla_metadata(ragged_dot_tiling=tiling):
+        return jax.lax.ragged_dot(x, w, sizes)
+
+
 def _grouped(x, w, sizes):
     """Rows of x grouped by expert times their expert's matrix.  Rows
     past the last group are left unspecified (a TPU leaves them
     unwritten)."""
-    return jax.lax.ragged_dot(x, w, sizes)
+    return _ragged_dot(x, w, sizes, _tiling(x.shape[0], *w.shape[1:], False))
 
 
 def _weight_grad(x, dy, w, sizes):
     """The gradient of ``_grouped(x, w, sizes)`` in w: each group's
     x^T dy, over its own rows alone."""
-    _, vjp = jax.vjp(lambda w: jax.lax.ragged_dot(x, w, sizes), w)
+    tiling = _tiling(x.shape[0], *w.shape[1:], True)
+    _, vjp = jax.vjp(lambda w: _ragged_dot(x, w, sizes, tiling), w)
     return vjp(dy)[0]
 
 

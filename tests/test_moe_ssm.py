@@ -165,6 +165,23 @@ def test_moe_rows_past_the_groups_never_reach_a_token(monkeypatch):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-4, atol=1e-5)
 
 
+@pytest.mark.parametrize("rows,k,n,weight_grad,want", [
+    # DeepSeek-V2-Lite's experts (2,048 x 1,408) over a 4,096-token
+    # sequence's 24,576 pairs: whole-width forward tiles, 512 x 512
+    # weight-gradient tiles
+    (24576, 2048, 1408, False, "512,512,1408"),
+    (24576, 1408, 2048, False, "512,128,2048"),
+    (24576, 2048, 1408, True, "512,512,512"),
+    (24576, 1408, 2048, True, "512,512,512"),
+    # rows that are no whole number of row tiles (decode's few tokens)
+    (48, 2048, 1408, False, None),
+    # a whole-width tile past the VMEM budget (dbrx's 10,752-wide experts)
+    (24576, 6144, 10752, False, None),
+])
+def test_moe_grouped_product_tiles(rows, k, n, weight_grad, want):
+    assert MOE._tiling(rows, k, n, weight_grad) == want
+
+
 def test_moe_router_without_renormalisation_keeps_probabilities():
     spec, p, x = _moe_setup(norm_topk_prob=False, routed_scaling_factor=2.0)
     xf = x.reshape(-1, x.shape[-1])

@@ -165,3 +165,26 @@ def test_bucket_round_reads_G_only_in_its_kernels(one_chip, monkeypatch):
     agg = re.search(rf"%{re.escape(root.group(1))} = \S+ "
                     r"get-tuple-element\(%([\w.\-]+)\)", entry)
     assert agg and agg.group(1) == "select_mean.1", root.group(0)
+
+
+def test_expert_products_compile_with_their_tiles(one_chip):
+    """DeepSeek-V2-Lite's held experts (8 of 2,048 x 1,408, f32) over a
+    4,096-token sequence's 24,576 pairs: the expert MLP's forward and
+    backward compile for v5e, and each of its nine grouped products
+    carries the tiles ``moe._tiling`` gives it."""
+    from repro.models import moe as MOE
+    R, D, F, E = 24576, 2048, 1408, 8
+
+    def loss(xs, w_in, w_gate, w_out, sizes):
+        return jnp.sum(MOE.expert_mlp(xs, w_in, w_gate, w_out, sizes, "silu"))
+
+    shapes = [(R, D), (E, D, F), (E, D, F), (E, F, D)]
+    args = [jax.ShapeDtypeStruct(s, jnp.float32, sharding=one_chip) for s in shapes]
+    args.append(jax.ShapeDtypeStruct((E,), jnp.int32, sharding=one_chip))
+    text = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2, 3))).lower(*args).compile().as_text()
+    kernels = re.findall(r"= (f32\[[\d,]+\])\S* custom-call\(.*?"
+                         r'ragged_dot_tiling="([\d,]+)"', text)
+    want = {f"f32[{R},{F}]": "512,512,1408", f"f32[{R},{D}]": "512,128,2048",
+            f"f32[{E},{D},{F}]": "512,512,512", f"f32[{E},{F},{D}]": "512,512,512"}
+    assert len(kernels) == 9, kernels
+    assert all(want[shape] == tiling for shape, tiling in kernels), kernels
